@@ -36,9 +36,25 @@ from repro.kernels import ref
 from repro.kernels.ops import (binarize_pack, binary_binary_dense,
                               binary_dense)
 from repro.kernels.packed import PackedArray
+from repro.launch.cache import use_compile_cache
 
-HBM_BW = 819e9
-PEAK = 197e12
+# Published per-chip peaks keyed by jax's device_kind (Google Cloud
+# documentation, "TPU v5e": 819 GB/s HBM, 197 TFLOP/s bf16).  A device
+# missing from the table is an error, never a default.
+PEAK_RATES = {
+    "TPU v5 lite": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
+}
+# the chip the host-side roofline model describes when no TPU is attached
+TARGET_KIND = "TPU v5 lite"
+
+
+def peak_rates(kind):
+    try:
+        return PEAK_RATES[kind]
+    except KeyError:
+        raise ValueError(f"no published peak rates for device kind "
+                         f"{kind!r}: add them to PEAK_RATES with their "
+                         f"source") from None
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 DEFAULT_OUT = os.path.join(_HERE, "BENCH_kernels.json")
@@ -94,7 +110,11 @@ def _env():
 
 
 def run(log=print, out_json=DEFAULT_OUT):
-    log("\n== Kernel roofline model (decode-shape binary GEMMs) ==")
+    dev = jax.devices()[0]
+    kind = dev.device_kind if dev.platform == "tpu" else TARGET_KIND
+    hbm_bw = peak_rates(kind)["hbm_bytes_per_s"]
+    log(f"\n== Kernel roofline model (decode-shape binary GEMMs, "
+        f"{kind} peaks) ==")
     shapes = [(128, 4096, 4096), (128, 12288, 12288), (1, 8192, 8192)]
     log(f"{'M,K,N':>18s} | {'bf16 MB':>9s} {'packedW':>9s} {'both':>9s} | "
         f"{'t_mem bf16':>10s} {'packedW':>9s} {'AI bf16':>8s} {'packedW':>8s}")
@@ -102,8 +122,8 @@ def run(log=print, out_json=DEFAULT_OUT):
     for m, k, n in shapes:
         b = model_bytes(m, k, n)
         flops = 2 * m * k * n
-        t_b = b["bf16"] / HBM_BW
-        t_p = b["packed_w"] / HBM_BW
+        t_b = b["bf16"] / hbm_bw
+        t_p = b["packed_w"] / hbm_bw
         rows.append({
             "m": m, "k": k, "n": n, "bytes": b,
             "flops": flops,
@@ -150,8 +170,10 @@ def run(log=print, out_json=DEFAULT_OUT):
         ", ".join(f"{k_}={v * 1e3:.2f}ms" for k_, v in measured.items()
                   if k_.endswith("_s")))
 
-    out = {"env": _env(), "hbm_bw_model": HBM_BW,
-           "peak_flops_model": PEAK, "roofline": rows,
+    out = {"env": _env(), "peak_rates_of": kind,
+           "hbm_bw_model": hbm_bw,
+           "peak_flops_model": peak_rates(kind)["bf16_flops_per_s"],
+           "roofline": rows,
            "spot_check_s": spot_s, "measured": measured}
     if out_json:
         with open(out_json, "w") as f:
@@ -1041,6 +1063,7 @@ if __name__ == "__main__":
                     help="small shapes for CI (with --fused/--conv/"
                          "--compile/--serve/--faults/--train/--dse)")
     args = ap.parse_args()
+    use_compile_cache()
 
     def dest_for(default):
         """Default output path; --smoke writes BENCH_*_smoke.json so a

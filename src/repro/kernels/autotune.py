@@ -1,11 +1,11 @@
 """Block-size autotuner for the binarized GEMM kernels.
 
-The kernels used to hard-code ``bm = bn = 128, bk32 = 16``.  Those are
-the right defaults for MXU/VPU-aligned shapes, but dispatch now routes
-every kernel launch through this module instead: a cached tuning table
-keyed on ``(op, backend, M, N, K32)`` returns the block sizes to use,
-falling back to a divisor-clamped heuristic on a miss (and memoizing
-it, so repeated shapes hit the cache).
+The kernels do not hard-code their tiles: dispatch routes every kernel
+launch through this module, where a cached tuning table keyed on
+``(op, backend, M, N, K32)`` returns the block sizes to use, falling
+back to a heuristic on a miss (128-row, 128-column, 128-word blocks
+clamped to whole TPU tiles) and memoizing it, so repeated shapes hit
+the cache.
 
 Entries can come from three places, in priority order:
 
@@ -31,8 +31,8 @@ KH*KW*C32; DESIGN.md SS7).
 
 Invariants / failure modes:
 * fused ``pack_out`` launches use a distinct "<op>+pack" op key — their
-  bn carries an extra %32 packing constraint, so an unfused tuned entry
-  (bn possibly < 32) must never be served to a fused launch;
+  bn tiles output *words* (csa.col_block), so an unfused tuned entry
+  (bn tiling columns) must never be served to a fused launch;
 * a malformed JSON table raises at ``load`` time (fail fast), while a
   missing ``$REPRO_TUNING_TABLE`` path is silently ignored (tuning is
   an optimization, not a dependency);
@@ -48,7 +48,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Dict, Iterable, Optional, Tuple
 
-from repro.kernels.csa import largest_divisor
+from repro.kernels.csa import LANES, SUBLANES, col_block, tile_divisor
 
 ENV_TABLE = "REPRO_TUNING_TABLE"
 
@@ -73,12 +73,14 @@ class BlockConfig:
         return cls(int(d["bm"]), int(d["bn"]), int(d["bk32"]))
 
 
-def _heuristic(m: int, n: int, k32: int, n_mult: int = 1) -> BlockConfig:
-    """Divisor-clamped version of the old hard-coded 128/128/16."""
+def _heuristic(op: str, m: int, n: int, k32: int) -> BlockConfig:
+    """128 rows x 128 columns x 128 words, clamped to whole TPU tiles
+    (a packed output tiles its words, see csa.col_block).  The CSA
+    kernels shrink the row block further (csa.row_block)."""
     return BlockConfig(
-        bm=largest_divisor(m, min(128, m)),
-        bn=largest_divisor(n, min(128, n), multiple_of=n_mult),
-        bk32=largest_divisor(k32, min(16, k32)))
+        bm=tile_divisor(m, 128, SUBLANES),
+        bn=col_block(n, LANES, op.endswith("+pack") and n % 32 == 0),
+        bk32=tile_divisor(k32, LANES, LANES))
 
 
 class TuningTable:
@@ -142,14 +144,13 @@ def best_blocks(op: str, m: int, n: int, k32: int,
 
     op: "popcount_gemm" | "xnor_gemm" | "fused_mlp" | "packed_conv" —
     part of the key because the ops have different VMEM/compute
-    balance; fused pack_out launches append "+pack" (their bn has an
-    extra %32 constraint, so tuned entries must not leak across)."""
+    balance; fused pack_out launches append "+pack" (their bn tiles
+    output words, so tuned entries must not leak across)."""
     key = (op, backend, m, n, k32)
     hit = _TABLE.get(key)
     if hit is not None:
         return hit
-    n_mult = 32 if n % 32 == 0 else 1      # keep bn packable when N is
-    return _TABLE.put(key, _heuristic(m, n, k32, n_mult=n_mult))
+    return _TABLE.put(key, _heuristic(op, m, n, k32))
 
 
 def warm(keys: Iterable[Key]) -> None:
@@ -178,20 +179,14 @@ def best_conv_blocks(op: str, ho: int, wo: int, f: int, k32: int,
 
 def candidate_blocks(m: int, n: int, k32: int) -> Iterable[BlockConfig]:
     """Sensible sweep for ``autotune``: power-of-two tiles clamped to
-    divisors, deduplicated."""
+    whole-tile divisors, deduplicated."""
     seen = set()
     for bm in (256, 128, 64, 32, 8):
-        for bn in (256, 128, 64, 32):
-            for bk in (32, 16, 8, 4):
-                try:
-                    cfg = BlockConfig(
-                        bm=largest_divisor(m, min(bm, m)),
-                        bn=largest_divisor(n, min(bn, n),
-                                           multiple_of=32 if n % 32 == 0
-                                           else 1),
-                        bk32=largest_divisor(k32, min(bk, k32)))
-                except ValueError:
-                    continue
+        for bn in (512, 256, 128):
+            for bk in (512, 256, 128):
+                cfg = BlockConfig(bm=tile_divisor(m, bm, SUBLANES),
+                                  bn=tile_divisor(n, bn, LANES),
+                                  bk32=tile_divisor(k32, bk, LANES))
                 if cfg not in seen:
                     seen.add(cfg)
                     yield cfg

@@ -21,8 +21,8 @@ gone from the tree; its jnp twin survives as `ref.popcount_gemm_ref`
 twin (`ref.popcount_gemm_csa_ref`) against.  Derivation: DESIGN.md §6.
 
 Inputs/outputs: all plane arguments are uint32 arrays of one common
-shape (any rank); `csa_fold` consumes a *list* of such planes plus the
-4-tuple state and returns the updated state; `csa_finalize` collapses
+shape (any rank); `csa_fold` consumes an iterable of such planes plus
+the 4-tuple state and returns the updated state; `csa_finalize` collapses
 the state to the int32 popcount total.
 
 Invariants / failure modes:
@@ -34,15 +34,16 @@ Invariants / failure modes:
   nothing — callers never need to align their plane counts;
 * `pack_bit_planes` requires bn % 32 == 0 (it emits whole words) and
   zeroes columns >= valid_n so its output satisfies the PackedArray
-  pad contract; the kernels guarantee the %32 by clamping bn UP for
-  pack_out launches;
-* `largest_divisor` raises ValueError (never asserts) when a dim is
-  not a multiple of the required alignment — the clear error legacy
-  raw-uint32 callers see instead of a block-divisibility assert.
+  pad contract; `col_block` keeps a pack_out block a whole number of
+  words (the kernels reject N % 32 != 0 up front);
+* `tile_divisor` / `col_block` are the one rule for block sizes: the
+  TPU compiler refuses a block whose two minor dims are neither whole
+  (8, 128) tiles nor the array's whole dims.
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+import itertools
+from typing import Iterable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +53,12 @@ import jax.numpy as jnp
 from repro.kernels.packed import popcount_u32 as popcount_word
 
 GROUP = 8  # planes folded per popcount; weights 1/2/4 remain as residues
+SUBLANES, LANES = 8, 128   # the (8, 128) tile every TPU block is made of
+# words in one XNOR plane (rows x columns) of a GEMM kernel: the v5e
+# compiler keeps about thirty planes of a block live in VMEM at once, so
+# 32K words (128 KB) keeps popcount_gemm and fused_mlp inside the 16 MB
+# scoped VMEM at BinaryNet widths (compile-checked: tests/test_tpu_compile.py)
+PLANE_WORDS = 32 * 1024
 
 
 def csa(a, b, c):
@@ -61,7 +68,7 @@ def csa(a, b, c):
     return u ^ c, (a & b) | (u & c)
 
 
-def csa_fold(planes: Sequence[jnp.ndarray], acc, ones, twos, fours
+def csa_fold(planes: Iterable[jnp.ndarray], acc, ones, twos, fours
              ) -> Tuple[jnp.ndarray, ...]:
     """Fold bit planes into a Harley-Seal state.
 
@@ -72,15 +79,19 @@ def csa_fold(planes: Sequence[jnp.ndarray], acc, ones, twos, fours
     with zero planes (zeros add nothing).  The invariant
     ``total = acc + pc(ones) + 2*pc(twos) + 4*pc(fours)`` holds after
     every call, so the state may be threaded across any block split of
-    the K axis (csa_finalize collapses it)."""
-    planes = list(planes)
-    if not planes:
-        return acc, ones, twos, fours
-    zero = jnp.zeros_like(planes[0])
-    while len(planes) % GROUP:
-        planes.append(zero)
-    for g in range(0, len(planes), GROUP):
-        d = planes[g:g + GROUP]
+    the K axis (csa_finalize collapses it).
+
+    ``planes`` is consumed lazily, one group at a time: pass a
+    generator, and each group's planes are traced just before they are
+    folded, so the kernel's live working set is one group plus the
+    state rather than every plane of the block (which overflows VMEM
+    at real widths)."""
+    it = iter(planes)
+    while True:
+        d = list(itertools.islice(it, GROUP))
+        if not d:
+            return acc, ones, twos, fours
+        d += [jnp.zeros_like(d[0])] * (GROUP - len(d))
         ones, t0 = csa(ones, d[0], d[1])
         ones, t1 = csa(ones, d[2], d[3])
         twos, f0 = csa(twos, t0, t1)
@@ -89,7 +100,6 @@ def csa_fold(planes: Sequence[jnp.ndarray], acc, ones, twos, fours
         twos, f1 = csa(twos, t0, t1)
         fours, e = csa(fours, f0, f1)
         acc = acc + GROUP * popcount_word(e)
-    return acc, ones, twos, fours
 
 
 def csa_finalize(acc, ones, twos, fours):
@@ -98,32 +108,62 @@ def csa_finalize(acc, ones, twos, fours):
             + 4 * popcount_word(fours))
 
 
-def pack_bit_planes(bits, valid_n: int, col0):
+def pack_bit_planes(bits, valid_n: Optional[int] = None, col0=0):
     """Shift-or a [bm, bn] boolean decision plane into uint32 words
     [bm, bn // 32], zeroing columns >= ``valid_n`` (global column index
     = ``col0`` + local index) so pad bits are 0 per the PackedArray
-    contract — the words feed the next layer's K axis directly."""
+    contract — the words feed the next layer's K axis directly.
+
+    Mosaic can neither split the lane axis into (words, 32) nor reduce
+    unsigned integers, so the shift-or runs on the MXU instead: word j
+    is ``bits @ P`` with ``P[32j + b, j] = 2**b``, done as two bf16
+    matmuls over the low and high 16 bit positions.  Every partial sum
+    is an integer below 2**16, exact in the f32 accumulator, so the
+    words are bit-identical to the canonical packer."""
     bm, bn = bits.shape
-    col = col0 + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
-    b = jnp.where(col < valid_n, bits, False)
-    b32 = b.astype(jnp.uint32).reshape(bm, bn // 32, 32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-    return jnp.sum(b32 << shifts, axis=-1, dtype=jnp.uint32)
+    nw = bn // 32
+    if valid_n is not None:
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
+        bits = bits & (col < valid_n)
+    r = jax.lax.broadcasted_iota(jnp.int32, (bn, nw), 0)
+    own = (r >> 5) == jax.lax.broadcasted_iota(jnp.int32, (bn, nw), 1)
+    pos = r & 31
+    weight = jnp.left_shift(1, pos & 15)
+    b16 = bits.astype(jnp.bfloat16)
+
+    def half(sel):
+        p = jnp.where(own & sel, weight, 0).astype(jnp.float32)
+        return jnp.dot(b16, p.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    words = half(pos < 16) | (half(pos >= 16) << 16)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
-def largest_divisor(n: int, cap: int, multiple_of: int = 1) -> int:
-    """Largest d <= cap with n % d == 0 and d % multiple_of == 0.
-
-    Raises ValueError when no such divisor exists (i.e. n itself is not
-    a multiple of ``multiple_of``) — the clear error raw-uint32 legacy
-    callers get instead of an opaque block-divisibility assert."""
-    if n % multiple_of:
-        raise ValueError(
-            f"dimension {n} is not a multiple of {multiple_of}; pad the "
-            f"operand (ops.py dispatch does this automatically) or pass "
-            f"a compatible shape")
-    for d in range(min(cap, n), 0, -1):
-        if n % d == 0 and d % multiple_of == 0:
+def tile_divisor(n: int, cap: int, tile: int) -> int:
+    """Largest divisor of ``n`` that is a multiple of ``tile`` and at
+    most ``max(cap, tile)``; ``n`` itself when there is none.  A TPU
+    block's two minor dims must be whole (8, 128) tiles or span the
+    array's dim, so this is the one legal clamp for every block size a
+    kernel takes from a caller or the tuning table."""
+    cap = max(cap, tile)
+    for d in range(min(cap, n) // tile * tile, 0, -tile):
+        if n % d == 0:
             return d
-    raise ValueError(f"no divisor of {n} is both <= {cap} and a "
-                     f"multiple of {multiple_of}")
+    return n
+
+
+def col_block(n: int, bn: int, pack_out: bool) -> int:
+    """Legal output-column block of an ``n``-column launch.  An int32
+    output blocks its columns in lane tiles; a ``pack_out`` output
+    emits ``bn // 32`` words per block, so its *word* count is what
+    must be lane-tiled (or span every word)."""
+    if not pack_out:
+        return tile_divisor(n, bn, LANES)
+    return 32 * tile_divisor(n // 32, bn // 32, LANES)
+
+
+def row_block(m: int, bm: int, plane_n: int) -> int:
+    """Legal row block whose [rows, ``plane_n``] XNOR planes stay
+    within PLANE_WORDS."""
+    return tile_divisor(m, min(bm, PLANE_WORDS // plane_n), SUBLANES)

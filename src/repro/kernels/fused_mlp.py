@@ -60,8 +60,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.autotune import best_blocks
-from repro.kernels.csa import (csa_finalize, csa_fold, largest_divisor,
-                               pack_bit_planes)
+from repro.kernels.csa import (csa_finalize, csa_fold, pack_bit_planes,
+                               row_block)
 from repro.kernels.ops import binary_binary_dense, classify_threshold
 from repro.kernels.packed import (VMEM_BUDGET_BYTES, PackedArray,
                                   get_backend)
@@ -80,7 +80,7 @@ def _layer_dot(h, w_ref, k_logical: int):
     n_p = wpt.shape[1]
     bm = h.shape[0]
     zero = jnp.zeros((bm, n_p), jnp.uint32)
-    planes = [~(h[:, t:t + 1] ^ wpt[t:t + 1, :]) for t in range(kw)]
+    planes = (~(h[:, t:t + 1] ^ wpt[t:t + 1, :]) for t in range(kw))
     acc, ones, twos, fours = csa_fold(
         planes, jnp.zeros((bm, n_p), jnp.int32), zero, zero, zero)
     pc = csa_finalize(acc, ones, twos, fours)
@@ -195,9 +195,10 @@ def stack_plan(m: int, k0: int, ns: Sequence[int],
     mp = kb.pad_m(m)
     n_max = max(g[1] for g in geom)
     # clamp the tuned bm to a divisor of the padded M like every other
-    # kernel — a stale table entry must not drop grid steps
-    bm = largest_divisor(mp, min(best_blocks(
-        "fused_mlp", mp, n_max, w0, kb.name).bm, mp))
+    # kernel — a stale table entry must not drop grid steps — and keep
+    # the widest layer's [bm, n_max] planes within csa.PLANE_WORDS
+    bm = row_block(mp, best_blocks("fused_mlp", mp, n_max, w0,
+                                   kb.name).bm, n_max)
     vmem = _vmem_bytes(bm, w0, geom)
     budget = VMEM_BUDGET_BYTES if budget is None else budget
     return {"mp": mp, "bm": bm, "w0": w0, "geom": tuple(geom),

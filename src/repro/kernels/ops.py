@@ -33,7 +33,7 @@ import numpy as np
 from repro.kernels import packed_conv as _pconv
 from repro.kernels import ref
 from repro.kernels.autotune import best_blocks, best_conv_blocks
-from repro.kernels.csa import largest_divisor
+from repro.kernels.csa import col_block
 from repro.kernels.pack import pack as _pack_kernel
 from repro.kernels.packed import (PackedArray, adopt_packed,
                                   default_backend, get_backend, round_up)
@@ -335,11 +335,8 @@ def plan_conv_launch(h: int, w: int, c: int, f: int, kh: int, kw: int,
         op = "packed_conv+pack" if pack_out else "packed_conv"
         blocks = best_conv_blocks(op, ho, wo, fp, kh * kw * c32, kb.name)
         # estimate with the bf the kernel will actually launch with
-        # (same clamp as packed_conv2d: up to 32 for pack_out, down to
-        # a divisor of the padded F)
-        bf_run = largest_divisor(
-            fp, min(max(blocks.bn, 32) if pack_out else blocks.bn, fp),
-            multiple_of=32 if pack_out else 1)
+        # (the same clamp as packed_conv2d)
+        bf_run = col_block(fp, blocks.bn, pack_out)
         budget = (_pconv.VMEM_BUDGET_BYTES if vmem_budget is None
                   else vmem_budget)
         vmem = conv_vmem_bytes(h + 2 * pad_h, w + 2 * pad_w, c32, kh, kw,

@@ -285,10 +285,12 @@ def register_backend(spec: BackendSpec) -> BackendSpec:
     return spec
 
 
+# K pads to 128 words (4096 bits): a word-axis block is a whole number
+# of lane tiles or the whole axis, so a K of up to 128 words is one block
 register_backend(BackendSpec("pallas", uses_kernels=True, interpret=False,
-                             m_align=128, n_align=128, k_align=512))
+                             m_align=128, n_align=128, k_align=4096))
 register_backend(BackendSpec("interpret", uses_kernels=True, interpret=True,
-                             m_align=128, n_align=128, k_align=512))
+                             m_align=128, n_align=128, k_align=4096))
 register_backend(BackendSpec("xla", uses_kernels=False, interpret=False))
 
 

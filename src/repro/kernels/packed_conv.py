@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.csa import (csa_finalize, csa_fold, largest_divisor,
+from repro.kernels.csa import (col_block, csa_finalize, csa_fold,
                                pack_bit_planes)
 from repro.kernels.packed import VMEM_BUDGET_BYTES
 
@@ -101,17 +101,19 @@ def _conv_kernel(x_ref, w_ref, *rest, kh: int, kw: int, stride: int,
     m = ho * wo
 
     # one [m, bf] XNOR plane per (tap, word) through the CSA network —
-    # identical fold order to popcount_gemm, just a different gather
-    planes = []
-    for i_kh in range(kh):
-        for i_kw in range(kw):
-            xm = _window(x, i_kh, i_kw, stride, ho, wo).reshape(m, c32)
-            base = (i_kh * kw + i_kw) * c32
-            for t in range(c32):
-                planes.append(~(xm[:, t:t + 1] ^ w[base + t:base + t + 1, :]))
+    # identical fold order to popcount_gemm, just a different gather;
+    # generated lazily so only one group of planes is live at a time
+    def planes():
+        for i_kh in range(kh):
+            for i_kw in range(kw):
+                xm = _window(x, i_kh, i_kw, stride, ho, wo).reshape(m, c32)
+                base = (i_kh * kw + i_kw) * c32
+                for t in range(c32):
+                    yield ~(xm[:, t:t + 1] ^ w[base + t:base + t + 1, :])
+
     zero = jnp.zeros((m, bf), jnp.uint32)
     acc, ones, twos, fours = csa_fold(
-        planes, jnp.zeros((m, bf), jnp.int32), zero, zero, zero)
+        planes(), jnp.zeros((m, bf), jnp.int32), zero, zero, zero)
     pc = csa_finalize(acc, ones, twos, fours)
     dot = 2 * (pc - (k_packed - k)) - k
 
@@ -145,9 +147,10 @@ def packed_conv2d(xw: jax.Array, ww: jax.Array, *, kh: int, kw: int,
 
     Returns int32 [N, HO*WO, F] (signed dot, or {-1,+1} with a
     threshold), or uint32 [N, HO*WO, F/32] with ``pack_out=True`` —
-    the caller reshapes to NHWC.  ``bf`` blocks the F axis (clamped to
-    the largest divisor; pack_out clamps up to the 32-column packing
-    minimum); each grid step keeps one sample's image VMEM-resident.
+    the caller reshapes to NHWC.  ``bf`` blocks the F axis (clamped by
+    csa.col_block to whole lane tiles — of words under pack_out, so a
+    packed launch below 4096 channels takes all F in one block); each
+    grid step keeps one sample's image VMEM-resident.
     """
     n, h_pad, w_pad, c32 = xw.shape
     taps_words, f = ww.shape
@@ -164,8 +167,7 @@ def packed_conv2d(xw: jax.Array, ww: jax.Array, *, kh: int, kw: int,
         if f % 32:
             raise ValueError(f"pack_out needs F % 32 == 0, got F={f}; "
                              f"pad F (ops.py dispatch does)")
-    bf = largest_divisor(f, min(max(bf, 32) if pack_out else bf, f),
-                         multiple_of=32 if pack_out else 1)
+    bf = col_block(f, bf, pack_out)
     valid_f = f if valid_f is None else valid_f
     m = ho * wo
 

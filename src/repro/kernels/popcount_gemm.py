@@ -29,17 +29,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.csa import (csa_finalize, csa_fold, largest_divisor,
-                               pack_bit_planes)
+from repro.kernels.csa import (LANES, col_block, csa_finalize, csa_fold,
+                               pack_bit_planes, row_block, tile_divisor)
 
 
 def _xnor_planes(xp, wpt):
-    """One [bm, bn] uint32 XNOR plane per K-word.
+    """One [bm, bn] uint32 XNOR plane per K-word, generated lazily so
+    csa_fold traces each group just before folding it.
 
     xp: [bm, bk32]; wpt: [bk32, bn] (weight block pre-transposed once
     per grid step — cheap vs the cube it replaces)."""
-    bk32 = xp.shape[1]
-    return [~(xp[:, t:t + 1] ^ wpt[t:t + 1, :]) for t in range(bk32)]
+    return (~(xp[:, t:t + 1] ^ wpt[t:t + 1, :])
+            for t in range(xp.shape[1]))
 
 
 def _kernel(xp_ref, wp_ref, *rest, n_k_blocks: int, k: int, k_packed: int,
@@ -90,7 +91,7 @@ def popcount_gemm(xp: jax.Array, wp: jax.Array, k: int,
                   threshold: Optional[int] = None,
                   threshold_vec: Optional[jax.Array] = None,
                   pack_out: bool = False, valid_n: Optional[int] = None,
-                  bm: int = 128, bn: int = 128, bk32: int = 16,
+                  bm: int = 128, bn: int = 128, bk32: int = LANES,
                   interpret: bool = False) -> jax.Array:
     """xp: [M, K32] uint32; wp: [N, K32] uint32; k = valid bit count.
 
@@ -100,8 +101,11 @@ def popcount_gemm(xp: jax.Array, wp: jax.Array, k: int,
     is fused: the kernel emits uint32 [M, N/32] packed sign words
     directly (bits at columns >= ``valid_n`` forced to 0 so the words
     satisfy the PackedArray pad contract).  Block sizes clamp to the
-    largest divisor of each dim; impossible constraints raise
-    ValueError instead of an opaque assert.
+    largest divisor of each dim that whole TPU tiles make (csa.
+    tile_divisor — a packed output tiles its words, so its bn covers
+    32*128 columns or all N), and bm shrinks until a [bm, bn] plane
+    fits csa.PLANE_WORDS; impossible constraints raise ValueError
+    instead of an opaque assert.
     """
     M, K32 = xp.shape
     N, K32w = wp.shape
@@ -118,12 +122,9 @@ def popcount_gemm(xp: jax.Array, wp: jax.Array, k: int,
         if N % 32:
             raise ValueError(f"pack_out needs N % 32 == 0, got N={N}; "
                              f"pad N (ops.py dispatch does)")
-    bm = largest_divisor(M, min(bm, M))
-    # pack_out packs 32 columns per word, so bn clamps UP to the minimum
-    # legal 32 first (a tuned unfused bn may be smaller)
-    bn = largest_divisor(N, min(max(bn, 32) if pack_out else bn, N),
-                         multiple_of=32 if pack_out else 1)
-    bk32 = largest_divisor(K32, min(bk32, K32))
+    bn = col_block(N, bn, pack_out)
+    bm = row_block(M, bm, bn)
+    bk32 = tile_divisor(K32, bk32, LANES)
     valid_n = N if valid_n is None else valid_n
 
     grid = (M // bm, N // bn, K32 // bk32)

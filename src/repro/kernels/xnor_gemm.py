@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.csa import largest_divisor, pack_bit_planes
+from repro.kernels.csa import (LANES, SUBLANES, col_block, pack_bit_planes,
+                               tile_divisor)
 
 
 def _kernel(x_ref, wp_ref, alpha_ref, *rest, n_k_blocks: int,
@@ -45,7 +46,9 @@ def _kernel(x_ref, wp_ref, alpha_ref, *rest, n_k_blocks: int,
     wp = wp_ref[...]                                 # [bk//32, bn] uint32
     shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 32, 1), 1)
     bits = (wp[:, None, :] >> shifts) & jnp.uint32(1)
-    w = (2.0 * bits.astype(jnp.float32) - 1.0).astype(x.dtype)
+    # Mosaic has no uint32 -> float cast; the bits fit int32 exactly
+    w = (2.0 * bits.astype(jnp.int32).astype(jnp.float32) - 1.0
+         ).astype(x.dtype)
     w = w.reshape(wp.shape[0] * 32, wp.shape[1])     # [bk, bn] +-1
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),
@@ -73,7 +76,7 @@ def xnor_gemm(x: jax.Array, wp: jax.Array, alpha: jax.Array,
               threshold: Optional[float] = None,
               threshold_vec: Optional[jax.Array] = None,
               pack_out: bool = False, valid_n: Optional[int] = None,
-              bm: int = 128, bn: int = 128, bk: int = 512,
+              bm: int = 128, bn: int = 128, bk: int = 32 * LANES,
               interpret: bool = False) -> jax.Array:
     """x: [M, K] bf16/f32; wp: [K//32, N] uint32; alpha: [N].
 
@@ -81,8 +84,10 @@ def xnor_gemm(x: jax.Array, wp: jax.Array, alpha: jax.Array,
     (static scalar or float [N] ``threshold_vec``), {-1,+1} in x.dtype.
     ``pack_out=True`` fuses the binarize+pack epilogue and returns
     uint32 [M, N/32] (bits at columns >= ``valid_n`` zeroed).  Block
-    sizes clamp to the largest divisor of each dim; impossible
-    constraints raise ValueError instead of an opaque assert.
+    sizes clamp to the largest divisor of each dim that whole TPU tiles
+    make (csa.tile_divisor; bk/32 weight rows are a sublane dim);
+    impossible constraints raise ValueError instead of an opaque
+    assert.
     """
     M, K = x.shape
     K32, N = wp.shape
@@ -99,12 +104,11 @@ def xnor_gemm(x: jax.Array, wp: jax.Array, alpha: jax.Array,
         if N % 32:
             raise ValueError(f"pack_out needs N % 32 == 0, got N={N}; "
                              f"pad N (ops.py dispatch does)")
-    bm = largest_divisor(M, min(bm, M))
-    # pack_out packs 32 columns per word, so bn clamps UP to the minimum
-    # legal 32 first (a tuned unfused bn may be smaller)
-    bn = largest_divisor(N, min(max(bn, 32) if pack_out else bn, N),
-                         multiple_of=32 if pack_out else 1)
-    bk = largest_divisor(K, min(bk, K), multiple_of=32)
+    bm = tile_divisor(M, bm, SUBLANES)
+    bn = col_block(N, bn, pack_out)
+    # the weight block is bk/32 rows tall (whole sublane tiles make
+    # x's bk lanes whole lane tiles too)
+    bk = 32 * tile_divisor(K32, bk // 32, SUBLANES)
     valid_n = N if valid_n is None else valid_n
 
     grid = (M // bm, N // bn, K // bk)

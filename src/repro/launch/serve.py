@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.configs import get_arch, reduced
 from repro.kernels.packed import tree_nbytes
+from repro.launch.cache import use_compile_cache
 from repro.models import model as M
 from repro.models.quantize import pack_model_params
 from repro.serving.bucketing import pow2_ceil
@@ -190,6 +191,7 @@ def main():
     ap.add_argument("--packed", action="store_true",
                     help="TULIP bit-packed weights")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -29,6 +29,7 @@ import numpy as np
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.configs import get_arch, reduced
 from repro.data import DataConfig, DataIterator
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.optim import adamw
@@ -125,6 +126,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -52,15 +52,12 @@ def fit_spec(shape: Sequence[int], want: Sequence[Any],
 
 def shard_act(x: jax.Array, want: Sequence[Any]) -> jax.Array:
     """with_sharding_constraint that no-ops outside a mesh context and
-    degrades gracefully on non-divisible dims."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.shape:
-            return x
-        spec = fit_spec(x.shape, want, mesh)
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    degrades gracefully on non-divisible dims (fit_spec replicates
+    them); any other sharding error propagates."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    return jax.lax.with_sharding_constraint(x, fit_spec(x.shape, want, mesh))
 
 
 # ------------------------------------------------------------------ #

@@ -13,11 +13,16 @@ the (trailing) feature axis, so row-sharding the leading word dim
 partitions whole packed rows — no word ever straddles two devices, and
 the packed output words come back bit-identical (tests/test_serving.py
 asserts this with assert_array_equal).
+
+The TPU compiler cannot partition a Pallas kernel on its own, so on a
+mesh the forward runs under ``shard_map`` (``data_parallel``): every
+device applies the whole plan to its own rows.  Rows are independent
+throughout the datapath, so this is exact.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.launch.mesh import make_local_mesh
 from repro.runtime.sharding import BATCH_AXES, fit_spec
 
-__all__ = ["data_mesh", "ensure_owned", "replicate", "shard_batch"]
+__all__ = ["data_mesh", "data_parallel", "ensure_owned", "replicate",
+           "shard_batch"]
 
 
 def data_mesh(model: int = 1) -> Mesh:
@@ -35,6 +41,39 @@ def data_mesh(model: int = 1) -> Mesh:
     the launch/mesh.py local-mesh shape, every device on "data" by
     default."""
     return make_local_mesh(model=model)
+
+
+def _batch_spec(tree: Any, mesh: Mesh) -> PartitionSpec:
+    """How ``shard_batch`` places a batch: its leading axis over the
+    data axes, or replicated when the rows do not divide them."""
+    rows = np.shape(jax.tree.leaves(tree)[0])[0]
+    return fit_spec((rows,), (BATCH_AXES,), mesh)
+
+
+def data_parallel(apply: Callable[..., Any], mesh: Optional[Mesh]
+                  ) -> Callable[..., Any]:
+    """``apply(params, x, valid_rows=None)`` as one program over
+    ``mesh``: params replicated, each device running the plan on the
+    rows ``shard_batch`` gave it.  A sharded batch runs every row of
+    the bucket and is cut to ``valid_rows`` afterwards (a row count
+    that does not divide the mesh cannot be split evenly); a batch too
+    small to shard runs whole, masked, on every device."""
+    if mesh is None:
+        return apply
+
+    def run(params: Any, x: Any, valid_rows: Optional[int] = None) -> Any:
+        spec = _batch_spec(x, mesh)
+        sharded = spec[0] is not None
+        fn = jax.shard_map(
+            lambda p, xs: apply(p, xs, None if sharded else valid_rows),
+            mesh=mesh, in_specs=(PartitionSpec(), spec), out_specs=spec,
+            check_vma=False)
+        out = fn(params, x)
+        if sharded and valid_rows is not None:
+            out = jax.tree.map(lambda leaf: leaf[:valid_rows], out)
+        return out
+
+    return run
 
 
 def shard_batch(tree: Any, mesh: Optional[Mesh]) -> Any:
@@ -45,10 +84,8 @@ def shard_batch(tree: Any, mesh: Optional[Mesh]) -> Any:
         return tree
 
     def put(leaf: Any) -> Any:
-        shape = np.shape(leaf)
-        want = (BATCH_AXES,) + (None,) * (len(shape) - 1)
-        spec = fit_spec(shape, want, mesh)
-        return jax.device_put(leaf, NamedSharding(mesh, spec))
+        return jax.device_put(leaf,
+                              NamedSharding(mesh, _batch_spec(leaf, mesh)))
 
     return jax.tree.map(put, tree)
 

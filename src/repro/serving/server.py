@@ -91,7 +91,12 @@ from repro.serving.errors import (
     ServerOverloaded,
     ServingError,
 )
-from repro.serving.placement import ensure_owned, replicate, shard_batch
+from repro.serving.placement import (
+    data_parallel,
+    ensure_owned,
+    replicate,
+    shard_batch,
+)
 
 __all__ = ["BNNServer"]
 
@@ -303,7 +308,7 @@ class BNNServer:
         if donate:
             _filter_donation_warning()
         self._apply_jit = jax.jit(
-            compiled.apply,
+            data_parallel(compiled.apply, mesh),
             **compiled.serving_jit_kwargs(donate),
         )
         self._chaos = chaos
@@ -386,7 +391,8 @@ class BNNServer:
             if self._fallback_jit is None:
                 fb = self.compiled.with_backend(self.fallback_backend)
                 self._fallback_jit = jax.jit(
-                    fb.apply, **fb.serving_jit_kwargs(donate=False)
+                    data_parallel(fb.apply, self.mesh),
+                    **fb.serving_jit_kwargs(donate=False),
                 )
             return self._fallback_jit
 

@@ -269,9 +269,10 @@ def test_segmentation_default_budget_fuses_whole_stack():
 
 def test_segmentation_budget_splits_stack():
     """A budget that fits 2 resident layers but not 3 splits the run
-    into two megakernel segments at the VMEM boundary."""
+    into two megakernel segments at the VMEM boundary (2048-wide planes
+    cap the row block at 16: ~1.73 MB for 2 layers, ~2.26 MB for 3)."""
     cb = graph.compile_dense_stack(2048, [2048] * 4,
-                                   vmem_budget=6_500_000)
+                                   vmem_budget=2_000_000)
     steps = _dense_steps(cb)
     assert [s.kind for s in steps] == ["fused_stack", "fused_stack"]
     assert steps[0].args["fc_indices"] == (0, 1)

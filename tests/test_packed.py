@@ -136,8 +136,9 @@ def test_backend_registry():
     assert get_backend("xla_test") is spec
     # padding policy: K pads to a word below k_align, k_align above
     be = get_backend("interpret")
-    assert be.pad_k(50) == 64 and be.pad_k(512) == 512
-    assert be.pad_k(544) == 1024
+    # (k_align is 128 words: a word-axis block is whole lane tiles)
+    assert be.pad_k(50) == 64 and be.pad_k(4096) == 4096
+    assert be.pad_k(544) == 544 and be.pad_k(4128) == 8192
     assert be.pad_m(37) == 128 and be.pad_n(200) == 256
 
 

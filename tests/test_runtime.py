@@ -173,11 +173,10 @@ def test_error_feedback_reduces_bias():
 
 
 def test_compressed_psum_single_axis():
-    from jax.experimental.shard_map import shard_map
     mesh = jax.make_mesh((1,), ("pod",))
     x = jnp.arange(32, dtype=jnp.float32) / 7.0
     err0 = jnp.zeros_like(x)
-    f = shard_map(lambda a, e: compressed_psum(a, "pod", e),
+    f = jax.shard_map(lambda a, e: compressed_psum(a, "pod", e),
                   mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     out, err = f(x, err0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x), atol=2e-2)
