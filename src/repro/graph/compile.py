@@ -27,6 +27,7 @@ this entry point.
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -48,6 +49,7 @@ from repro.graph.passes import (PlanStep, batches_tuning_keys, build_plan,
 from repro.kernels import ops as kops
 from repro.kernels.fused_mlp import fused_binary_mlp
 from repro.kernels.packed import PackedArray
+from repro.runtime import spans
 
 __all__ = ["CompiledBNN", "compile", "compile_dense_stack",
            "serve_folded_stack"]
@@ -238,60 +240,68 @@ class CompiledBNN:
         bucket-padded batch stops paying GEMM work for its pad rows.
         Bit-identical to ``apply(params, x)[:valid_rows]``; under jit
         it must be a static argument (``serving_jit_kwargs``)."""
-        be = self.backend
         h: Any = x if valid_rows is None else kops.mask_rows(x, valid_rows)
         for step in self.plan:
-            a = step.args
-            if step.kind == "integer_conv":
-                p = params["conv"][a["conv_idx"]]
-                h = binary_weight_conv(h, p["w"], stride=a["stride"],
-                                       padding=a["pad"],
-                                       alpha=p["alpha"])
-            elif step.kind == "float_pool":
-                h = _maxpool_float(h, a["window"], a["stride"])
-            elif step.kind == "binarize":
-                if a["flatten"]:
-                    h = h.reshape(h.shape[0], -1)
-                h = kops.binarize_pack(h, backend=be)
-            elif step.kind == "binary_conv":
-                p = params["conv"][a["conv_idx"]]
-                h = binary_conv(h, p["wf"], fold=p["t"],
-                                stride=a["stride"], padding=a["pad"],
-                                pack_out=True, backend=be,
-                                impl=a["impl"])
-            elif step.kind == "packed_pool":
-                h = maxpool_packed(h, a["window"], a["stride"])
-            elif step.kind == "flatten":
-                if h.length % 32:
-                    raise ValueError(
-                        f"flattening needs C % 32 == 0 to keep the "
-                        f"word layout contiguous, got C={h.length}")
-                nb = h.words.shape[0]
-                spatial = h.words.shape[1] * h.words.shape[2]
-                h = PackedArray(h.words.reshape(nb, -1),
-                                length=spatial * h.length, axis=-1)
-                if h.length != a["n_in"]:
-                    raise ValueError(f"flattened width {h.length} != "
-                                     f"{step.name} n_in={a['n_in']}")
-            elif step.kind == "fused_stack":
-                ws, ts = [], []
-                for j in a["fc_indices"]:
-                    wp, t = _bind_dense(params["fc"][j])
-                    ws.append(wp)
-                    ts.append(t)
-                # thread the compile-time budget so the kernel's own
-                # residency re-check uses the same rule as the plan
-                h = fused_binary_mlp(h, ws, ts, backend=be,
-                                     vmem_budget=self.vmem_budget)
-            elif step.kind == "dense":
-                wp, t = _bind_dense(params["fc"][a["fc_idx"]])
-                h = kops.binary_binary_dense(
-                    h, wp, threshold=t if a["thresholded"] else None,
-                    pack_out=a["pack_out"], backend=be)
-            elif step.kind == "logits":
-                h = h.astype(jnp.float32)
-            else:                      # pragma: no cover
-                raise AssertionError(f"unknown plan step {step.kind}")
+            # the scope names the step in the device trace's op metadata
+            with jax.named_scope(f"{step.kind}.{step.name}"):
+                h = self._apply_step(step, params, h)
+        return h
+
+    def _apply_step(self, step: PlanStep, params: Dict[str, Any],
+                    h: Any) -> Any:
+        """One plan step of ``apply``."""
+        be = self.backend
+        a = step.args
+        if step.kind == "integer_conv":
+            p = params["conv"][a["conv_idx"]]
+            h = binary_weight_conv(h, p["w"], stride=a["stride"],
+                                   padding=a["pad"],
+                                   alpha=p["alpha"])
+        elif step.kind == "float_pool":
+            h = _maxpool_float(h, a["window"], a["stride"])
+        elif step.kind == "binarize":
+            if a["flatten"]:
+                h = h.reshape(h.shape[0], -1)
+            h = kops.binarize_pack(h, backend=be)
+        elif step.kind == "binary_conv":
+            p = params["conv"][a["conv_idx"]]
+            h = binary_conv(h, p["wf"], fold=p["t"],
+                            stride=a["stride"], padding=a["pad"],
+                            pack_out=True, backend=be,
+                            impl=a["impl"])
+        elif step.kind == "packed_pool":
+            h = maxpool_packed(h, a["window"], a["stride"])
+        elif step.kind == "flatten":
+            if h.length % 32:
+                raise ValueError(
+                    f"flattening needs C % 32 == 0 to keep the "
+                    f"word layout contiguous, got C={h.length}")
+            nb = h.words.shape[0]
+            spatial = h.words.shape[1] * h.words.shape[2]
+            h = PackedArray(h.words.reshape(nb, -1),
+                            length=spatial * h.length, axis=-1)
+            if h.length != a["n_in"]:
+                raise ValueError(f"flattened width {h.length} != "
+                                 f"{step.name} n_in={a['n_in']}")
+        elif step.kind == "fused_stack":
+            ws, ts = [], []
+            for j in a["fc_indices"]:
+                wp, t = _bind_dense(params["fc"][j])
+                ws.append(wp)
+                ts.append(t)
+            # thread the compile-time budget so the kernel's own
+            # residency re-check uses the same rule as the plan
+            h = fused_binary_mlp(h, ws, ts, backend=be,
+                                 vmem_budget=self.vmem_budget)
+        elif step.kind == "dense":
+            wp, t = _bind_dense(params["fc"][a["fc_idx"]])
+            h = kops.binary_binary_dense(
+                h, wp, threshold=t if a["thresholded"] else None,
+                pack_out=a["pack_out"], backend=be)
+        elif step.kind == "logits":
+            h = h.astype(jnp.float32)
+        else:                      # pragma: no cover
+            raise AssertionError(f"unknown plan step {step.kind}")
         return h
 
     # -------------------------------------------------------------- #
@@ -391,11 +401,15 @@ def compile(spec: Union[BNNSpec, Workload],
     time and are bit-identical either way); conv_impl: force
     "direct"/"im2col" instead of the "auto" VMEM estimate.
     """
+    t0 = time.perf_counter() if spans.on else 0.0
     if isinstance(spec, Workload):
         spec = from_workload(spec)
     spec.validate()
     plan = build_plan(spec, backend=backend, vmem_budget=vmem_budget,
                       batch=batch, conv_impl=conv_impl)
+    if t0:
+        spans.record("setup.compile", t0, time.perf_counter(),
+                     steps=len(plan))
     return CompiledBNN(spec, plan, backend, vmem_budget, batch)
 
 

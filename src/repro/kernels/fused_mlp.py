@@ -149,6 +149,7 @@ def _build_call(meta_key) -> callable:
         scratch_shapes=[pltpu.VMEM((bm, buf_words), jnp.uint32),
                         pltpu.VMEM((bm, buf_words), jnp.uint32)],
         interpret=interpret,
+        name="fused_mlp",
     )
     return jax.jit(lambda *ops: call(*ops))
 

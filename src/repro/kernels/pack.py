@@ -42,4 +42,5 @@ def pack(x: jax.Array, bm: int = 128, bk: int = 32 * LANES,
         out_specs=pl.BlockSpec((bm, bk // 32), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, K // 32), jnp.uint32),
         interpret=interpret,
+        name="pack",
     )(x)

@@ -198,6 +198,7 @@ def packed_conv2d(xw: jax.Array, ww: jax.Array, *, kh: int, kw: int,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="packed_conv2d",
     )(*operands)
 
 

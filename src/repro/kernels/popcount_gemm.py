@@ -157,4 +157,5 @@ def popcount_gemm(xp: jax.Array, wp: jax.Array, k: int,
                         pltpu.VMEM((bm, bn), jnp.uint32),
                         pltpu.VMEM((bm, bn), jnp.uint32)],
         interpret=interpret,
+        name="popcount_gemm",
     )(*operands)

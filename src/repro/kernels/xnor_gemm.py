@@ -138,4 +138,5 @@ def xnor_gemm(x: jax.Array, wp: jax.Array, alpha: jax.Array,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="xnor_gemm",
     )(*operands)

@@ -51,7 +51,9 @@ does not provide:
   real occupancy, HBM bytes from ``CompiledBNN.traffic``, an
   ``inflight_batches`` gauge, p50/p95/p99 queue-wait and end-to-end
   latency percentiles, the fault/recovery counters, and the straggler
-  watchdog's flags (runtime/straggler.py fed per-flight wall times).
+  watchdog's flags (runtime/straggler.py fed per-flight wall times);
+  with the span recorder on (runtime/spans.py), each boundary a
+  request and its flight cross is a span (DESIGN.md §10).
 
 Inputs are float ``[B, H, W, C]`` arrays for image specs or
 ``PackedArray [B, K]`` (packed on the last axis) for dense-entry
@@ -75,6 +77,7 @@ import numpy as np
 
 from repro.kernels import autotune
 from repro.kernels.packed import PackedArray
+from repro.runtime import spans
 from repro.runtime.straggler import StepWatchdog, WatchdogConfig
 from repro.serving.bucketing import (
     bucket_for,
@@ -162,6 +165,11 @@ def _kind_of(x: Any) -> Tuple:
     return ("dense", tuple(np.shape(x)[1:]), str(dt))
 
 
+def _nbytes(x: Any) -> int:
+    """Bytes of a staged payload's array leaves."""
+    return sum(int(leaf.nbytes) for leaf in jax.tree.leaves(x))
+
+
 def _pcts(samples: List[float]) -> Dict[str, float]:
     """mean/p50/p95/p99/max of a non-empty pre-sorted sample list."""
     n = len(samples)
@@ -204,7 +212,8 @@ def _is_retryable(e: BaseException) -> bool:
 
 
 class _Request:
-    __slots__ = ("x", "rows", "kind", "future", "t_enqueue", "deadline")
+    __slots__ = ("x", "rows", "kind", "future", "t_enqueue", "deadline",
+                 "flight")
 
     def __init__(
         self,
@@ -221,6 +230,7 @@ class _Request:
         self.future = future
         self.t_enqueue = t_enqueue
         self.deadline = deadline
+        self.flight = 0         # the span id of its flight (0: none)
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now >= self.deadline
@@ -397,7 +407,14 @@ class BNNServer:
             return self._fallback_jit
 
     def _run(
-        self, x: Any, bucket: int, valid: int, owned: bool, fallback: bool = False
+        self,
+        x: Any,
+        bucket: int,
+        valid: int,
+        owned: bool,
+        fallback: bool = False,
+        flight: int = 0,
+        first: bool = False,
     ) -> Any:
         """Pad to the bucket, place on the mesh, and ENQUEUE the masked
         forward — asynchronous: the caller decides when (and on which
@@ -405,7 +422,13 @@ class BNNServer:
         server-owned buffer: padding and placement create fresh ones,
         and the one aliasing case (exact-bucket rows arriving in a
         caller-held array) is defensively copied.  The fallback path
-        never donates at all (its jit has no donate_argnums)."""
+        never donates at all (its jit has no donate_argnums).
+
+        With the span recorder on, the chunk's host preparation and
+        host-to-device copy is a ``serve.stage`` span and the jit call
+        a ``serve.enqueue`` span (``first``: the level's first touch,
+        so the call traces and compiles or loads from the cache)."""
+        t0 = time.perf_counter() if spans.on else 0.0
         xp = _pad_rows(x, bucket)
         if fallback:
             fn = self._fallback_fn()
@@ -414,9 +437,20 @@ class BNNServer:
             if self.donate and xp is x and not owned:
                 xp = ensure_owned(xp)
         xs = shard_batch(xp, self.mesh)
-        return fn(self.params, xs, valid_rows=valid)
+        if not t0:
+            return fn(self.params, xs, valid_rows=valid)
+        t1 = time.perf_counter()
+        out = fn(self.params, xs, valid_rows=valid)
+        chunk = spans.new_id()
+        spans.record("serve.stage", t0, t1, chunk, flight, bucket=bucket,
+                     valid=valid, bytes=_nbytes(xs))
+        spans.record("serve.enqueue", t1, time.perf_counter(), chunk, flight,
+                     first=int(first))
+        return out
 
-    def _launch(self, x: Any, rows: int, owned: bool, fallback: bool = False) -> Any:
+    def _launch(
+        self, x: Any, rows: int, owned: bool, fallback: bool = False, flight: int = 0
+    ) -> Any:
         """Async-dispatch one micro-batch at its (bucket, valid) level;
         returns the UNRESOLVED output (``valid`` >= ``rows`` rows).
 
@@ -432,17 +466,17 @@ class BNNServer:
         valid = ragged_valid(rows, bucket)
         hit: Optional[bool] = None
         if fallback:
-            out = self._run(x, bucket, valid, owned, fallback=True)
+            out = self._run(x, bucket, valid, owned, fallback=True, flight=flight)
         else:
             key = (bucket, valid, _kind_of(x))
             with self._trace_lock:
                 hit = key in self._traced
                 if not hit:
                     self._warm(valid)
-                    out = self._run(x, bucket, valid, owned)
+                    out = self._run(x, bucket, valid, owned, flight=flight, first=True)
                     self._traced.add(key)
             if hit:
-                out = self._run(x, bucket, valid, owned)
+                out = self._run(x, bucket, valid, owned, flight=flight)
         with self._stats_lock:
             if hit is True:
                 self._bucket_hits += 1
@@ -456,18 +490,19 @@ class BNNServer:
         return out
 
     def _launch_chunks(
-        self, x: Any, rows: int, multi: bool, fallback: bool = False
+        self, x: Any, rows: int, multi: bool, fallback: bool = False, flight: int = 0
     ) -> List[Tuple[Any, int]]:
         """Async-launch a payload as max_batch chunks + remainder;
         returns [(unresolved out, chunk rows)].  ``multi``: the payload
-        was coalesced from several requests (already server-owned)."""
+        was coalesced from several requests (already server-owned);
+        ``flight``: the span id the chunks' spans name as parent."""
         outs: List[Tuple[Any, int]] = []
         chunks = split_rows(rows, self.max_batch)
         off = 0
         for chunk in chunks:
             piece = x if len(chunks) == 1 else _slice_rows(x, off, off + chunk)
             owned = multi or len(chunks) > 1
-            outs.append((self._launch(piece, chunk, owned, fallback), chunk))
+            outs.append((self._launch(piece, chunk, owned, fallback, flight), chunk))
             off += chunk
         return outs
 
@@ -541,13 +576,23 @@ class BNNServer:
     def _take_microbatch(self) -> List[_Request]:
         """Pop a FIFO run of requests whose rows coalesce under
         ``max_batch`` (an oversized head request comes back alone and
-        is chunked by ``_launch_chunks``).  Only same-kind payloads
-        coalesce: a request whose trailing shape/dtype differs from the
-        head's starts its own micro-batch, so one malformed request can
-        never fail its neighbors' futures."""
+        is chunked by ``_launch_chunks``)."""
         taken: List[_Request] = []
-        total = 0
-        kind = None
+        self._pop_run(taken, 0, None)
+        return taken
+
+    def _pop_run(
+        self, taken: List[_Request], total: int, kind: Optional[Tuple]
+    ) -> Tuple[int, Optional[Tuple], bool]:
+        """Move the queue's FIFO head run onto ``taken`` (holding
+        ``total`` rows of ``kind``) while it stays within ``max_batch``
+        rows; returns (total, kind, backlog: requests still queued).
+        Only same-kind payloads coalesce: a request whose trailing
+        shape/dtype differs from the head's starts its own micro-batch,
+        so one malformed request can never fail its neighbors' futures.
+        With the span recorder on, each request taken ends its
+        ``serve.queue`` span and joins its flight's span id."""
+        n0 = len(taken)
         with self._qlock:
             while self._queue:
                 nxt = self._queue[0]
@@ -562,7 +607,15 @@ class BNNServer:
                 total += nxt.rows
                 if total >= self.max_batch:
                     break
-        return taken
+            backlog = bool(self._queue)
+        if spans.on and len(taken) > n0:
+            t = time.perf_counter()
+            flight = taken[0].flight or spans.new_id()
+            for r in taken[n0:]:
+                r.flight = flight
+                spans.record("serve.queue", r.t_enqueue, t, spans.new_id(), flight,
+                             rows=r.rows)
+        return total, kind, backlog
 
     def _admit(self) -> List[_Request]:
         """Continuous-batching admission: build the next micro-batch,
@@ -583,25 +636,14 @@ class BNNServer:
         overlapped with device compute."""
         taken: List[_Request] = []
         total = 0
-        kind = None
+        kind: Optional[Tuple] = None
         deadline: Optional[float] = None
+        t_first = 0.0
         while not self._stop.is_set():
             self._chaos_kill("dispatcher")
-            with self._qlock:
-                while self._queue:
-                    nxt = self._queue[0]
-                    if taken and total + nxt.rows > self.max_batch:
-                        break
-                    if taken and nxt.kind != kind:
-                        break
-                    if not taken:
-                        kind = nxt.kind
-                    taken.append(self._queue.popleft())
-                    self._queued_rows -= nxt.rows
-                    total += nxt.rows
-                    if total >= self.max_batch:
-                        break
-                backlog = bool(self._queue)
+            total, kind, backlog = self._pop_run(taken, total, kind)
+            if taken and not t_first and spans.on:
+                t_first = time.perf_counter()
             if taken and (total >= self.max_batch or backlog):
                 break
             if taken:
@@ -617,6 +659,9 @@ class BNNServer:
                 timeout = 0.05
             self._wake.wait(timeout=timeout)
             self._wake.clear()
+        if t_first:
+            spans.record("serve.admit", t_first, time.perf_counter(),
+                         taken[0].flight, requests=len(taken), rows=total)
         return taken
 
     # -- fault handling (DESIGN.md §11) ------------------------------ #
@@ -656,12 +701,32 @@ class BNNServer:
         self._chaos_flight(reqs, fallback)
         x = _concat_rows([r.x for r in reqs])
         rows = sum(r.rows for r in reqs)
-        outs = self._launch_chunks(x, rows, multi=len(reqs) > 1, fallback=fallback)
+        outs = self._launch_chunks(
+            x, rows, multi=len(reqs) > 1, fallback=fallback, flight=reqs[0].flight
+        )
         return self._finish_chunks(outs)
 
-    def _recover(
-        self, reqs: List[_Request], exc: BaseException, top: bool = True
-    ) -> None:
+    def _recover(self, reqs: List[_Request], exc: BaseException) -> None:
+        """Run the recovery ladder (``_climb``) for one failed flight and
+        count it; with the span recorder on, the ladder's run is a
+        ``serve.recover`` span carrying the fallbacks, retries and
+        bisections the server counted meanwhile."""
+        with self._stats_lock:
+            self._flight_faults += 1
+        t0 = time.perf_counter() if spans.on else 0.0
+        if t0:
+            before = self._ladder_counts()
+        self._climb(reqs, exc)
+        if t0:
+            moved = [b - a for a, b in zip(before, self._ladder_counts())]
+            spans.record("serve.recover", t0, time.perf_counter(), reqs[0].flight,
+                         fallback=moved[0], retries=moved[1], bisections=moved[2])
+
+    def _ladder_counts(self) -> Tuple[int, int, int]:
+        with self._stats_lock:
+            return self._backend_fallbacks, self._retries, self._bisections
+
+    def _climb(self, reqs: List[_Request], exc: BaseException) -> None:
         """The recovery ladder for a failed flight: backend fallback ->
         bounded retry with backoff -> bisection -> typed singleton
         failure.  Every future in ``reqs`` is resolved (value or typed
@@ -682,13 +747,7 @@ class BNNServer:
           ladder applies at every bisection level — a backend fault
           landing on a half mid-bisection still degrades to the
           fallback path instead of failing healthy requests.
-
-        ``top`` marks the outermost call (one per failed flight) for
-        the fault counter; recursion runs with top=False.
         """
-        if top:
-            with self._stats_lock:
-                self._flight_faults += 1
         if self.fallback_backend is not None and _is_backend_fault(exc):
             try:
                 out = self._execute(reqs, fallback=True)
@@ -719,7 +778,7 @@ class BNNServer:
                 try:
                     out = self._execute(half)
                 except Exception as e:
-                    self._recover(half, e, top=False)
+                    self._climb(half, e)
                 else:
                     self._resolve(half, out)
             return
@@ -751,15 +810,27 @@ class BNNServer:
         taken = self._shed_expired(taken)
         if not taken:
             return
+        flight = taken[0].flight
         acquired = False
         t_launch = time.perf_counter()
         try:
             self._chaos_flight(taken, False)
             x = _concat_rows([r.x for r in taken])
             rows = sum(r.rows for r in taken)
+            t_wait = time.perf_counter() if spans.on else 0.0
             self._ahead_sem.acquire()
             acquired = True
-            outs = self._launch_chunks(x, rows, multi=len(taken) > 1)
+            # a request's queue wait ends here, once its flight holds a
+            # dispatch-ahead slot
+            t_slot = time.perf_counter()
+            if t_wait:
+                if len(taken) > 1:
+                    bucket = bucket_for(rows, self.max_batch)
+                    spans.record("serve.stage", t_launch, t_wait, spans.new_id(),
+                                 flight, bucket=bucket,
+                                 valid=ragged_valid(rows, bucket), bytes=_nbytes(x))
+                spans.record("serve.ahead_wait", t_wait, t_slot, flight)
+            outs = self._launch_chunks(x, rows, multi=len(taken) > 1, flight=flight)
         except Exception as e:
             if acquired:
                 self._ahead_sem.release()
@@ -770,7 +841,7 @@ class BNNServer:
             self._inflight_n += 1
             self._inflight_peak = max(self._inflight_peak, self._inflight_n)
             for r in taken:
-                self._queue_waits.append(t_launch - r.t_enqueue)
+                self._queue_waits.append(t_slot - r.t_enqueue)
         self._launched.put(_Flight(taken, outs, t_launch))
 
     def _serve_one(self, taken: List[_Request]) -> None:
@@ -792,8 +863,10 @@ class BNNServer:
             self._resolve(taken, out)
         self._observe_wall(time.perf_counter() - t_start)
 
-    def _resolve(self, taken: List[_Request], out: Any) -> None:
-        """Slice a completed micro-batch result back to its requests."""
+    def _resolve(self, taken: List[_Request], out: Any) -> float:
+        """Slice a completed micro-batch result back to its requests;
+        returns the time it started (a ``serve.resolve`` span's start
+        with the span recorder on)."""
         t_done = time.perf_counter()
         off = 0
         for r in taken:
@@ -803,6 +876,9 @@ class BNNServer:
                 self._n_requests += 1
                 self._n_rows += r.rows
                 self._latencies.append(t_done - r.t_enqueue)
+        if spans.on:
+            spans.record("serve.resolve", t_done, time.perf_counter(), taken[0].flight)
+        return t_done
 
     def flush(self) -> int:
         """Drain the queue synchronously; returns micro-batches run.
@@ -876,14 +952,19 @@ class BNNServer:
 
     def _complete_one(self, fl: _Flight) -> None:
         """Resolve one launched flight (failures climb the recovery
-        ladder); ALWAYS releases its dispatch-ahead slot."""
+        ladder); ALWAYS releases its dispatch-ahead slot.  With the span
+        recorder on, the wait for the device is a ``serve.device_wait``
+        span."""
+        t_wait = time.perf_counter() if spans.on else 0.0
         try:
             try:
                 out = self._finish_chunks(fl.outs)
             except Exception as e:
                 self._recover(fl.reqs, e)
             else:
-                self._resolve(fl.reqs, out)
+                t_done = self._resolve(fl.reqs, out)
+                if t_wait:
+                    spans.record("serve.device_wait", t_wait, t_done, fl.reqs[0].flight)
         finally:
             self._observe_wall(time.perf_counter() - fl.t_launch)
             with self._stats_lock:

@@ -374,6 +374,23 @@ def test_worker_thread_async_dispatch():
     assert srv.jit_traces() <= srv.trace_bound()
 
 
+def test_queue_wait_counts_the_wait_for_a_dispatch_slot():
+    """A request's queue wait runs until its flight holds a
+    dispatch-ahead slot: with the one slot held, the wait shows."""
+    cb, params, srv = _mlp_server(max_batch=8, dispatch_ahead=1)
+    rng = np.random.default_rng(13)
+    srv.start()
+    try:
+        srv._ahead_sem.acquire()
+        fut = srv.submit(_packed(rng, 3))
+        time.sleep(0.2)
+        srv._ahead_sem.release()
+        fut.result(timeout=60)
+    finally:
+        srv.stop()
+    assert srv.stats()["queue_wait_s"]["max"] >= 0.2
+
+
 def test_stop_resolves_batches_in_flight():
     """stop() with work queued and batches in flight: every future
     resolves before stop returns, the in-flight gauge drops to zero,
