@@ -87,6 +87,7 @@ _PROTECTED: Dict[str, "frozenset[str]"] = {
             "_valid_rows",
             "_real_rows",
             "_hbm_bytes",
+            "_flat_staged",
             "_inflight_n",
             "_inflight_peak",
             "_flight_faults",

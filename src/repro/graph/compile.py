@@ -165,7 +165,10 @@ class CompiledBNN:
           backends that honor donation (TPU/GPU; CPU ignores it).
           The caller must therefore pass a buffer it owns —
           ``BNNServer`` pads/copies into a server-owned staging buffer
-          before every donated dispatch (DESIGN.md §10).  ``params``
+          before every donated dispatch; host image rows reach it as
+          a fresh flat ``[rows, H*W*C]`` device buffer, which the
+          server's wrapper reshapes back inside the jit (DESIGN.md
+          §10).  ``params``
           (argnum 0) are NEVER donated: they are replicated once and
           reused by every dispatch.
         """

@@ -101,7 +101,9 @@ def ensure_owned(tree: Any) -> Any:
     straight into the donated slot — this copy is what keeps the
     donation contract one-sided (the server only ever donates buffers
     it created; a caller-held PackedArray is never invalidated,
-    tests/test_serving.py asserts it)."""
+    tests/test_serving.py asserts it).  For host image rows, which the
+    server stages as a flat ``[rows, H*W*C]`` view (DESIGN.md §10),
+    the copy is the host-to-device transfer of that view."""
     return jax.tree.map(lambda leaf: jnp.array(leaf, copy=True), tree)
 
 

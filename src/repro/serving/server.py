@@ -59,6 +59,9 @@ Inputs are float ``[B, H, W, C]`` arrays for image specs or
 ``PackedArray [B, K]`` (packed on the last axis) for dense-entry
 specs; outputs keep the compiled pipeline's type (float logits or a
 PackedArray), always sliced back to the request's true row count.
+Host (numpy) image rows move to the device flat, as ``[B, H*W*C]``,
+and the jitted forward restores NHWC (DESIGN.md §10, "Staging host
+image rows").
 """
 
 from __future__ import annotations
@@ -153,6 +156,34 @@ def _concat_rows(xs: Sequence[Any]) -> Any:
                 raise ValueError("cannot coalesce differently-laid-out rows")
         return first.with_words(jnp.concatenate([x.words for x in xs], axis=0))
     return jnp.concatenate([jnp.asarray(x) for x in xs], axis=0)
+
+
+def _flat_rows(x: Any, row_shape: Tuple[int, ...]) -> Optional[np.ndarray]:
+    """A host image batch as ``[rows, H*W*C]`` (a free view of a
+    contiguous array), or None where ``x`` is not one: a device-resident
+    ``jax.Array`` (reshaping it outside the jit would add a device op),
+    a PackedArray (already 2-D words), or rows of another shape.  A 4-D
+    host array's transfer pays for the chip's tiled layout of its minor
+    dims (W x C); the same bytes move flat in well under half the time
+    (DESIGN.md §10)."""
+    if isinstance(x, np.ndarray) and x.ndim > 2 and x.shape[1:] == row_shape:
+        return x.reshape(x.shape[0], -1)
+    return None
+
+
+def _restore_rows(apply: Any, row_shape: Tuple[int, ...]) -> Any:
+    """``apply(params, x, valid_rows)`` that first reshapes rows staged
+    flat back to ``row_shape``: an exact reshape, the forward's first op
+    (inside ``data_parallel``, each device restores its own rows)."""
+    if len(row_shape) < 2:
+        return apply
+
+    def run(params: Any, x: Any, valid_rows: Optional[int] = None) -> Any:
+        if not isinstance(x, PackedArray) and x.ndim == 2:
+            x = x.reshape((x.shape[0],) + row_shape)
+        return apply(params, x, valid_rows)
+
+    return run
 
 
 def _kind_of(x: Any) -> Tuple:
@@ -317,8 +348,9 @@ class BNNServer:
         self.params = replicate(params, mesh)
         if donate:
             _filter_donation_warning()
+        self._row_shape = tuple(compiled.spec.input_shape)
         self._apply_jit = jax.jit(
-            data_parallel(compiled.apply, mesh),
+            data_parallel(_restore_rows(compiled.apply, self._row_shape), mesh),
             **compiled.serving_jit_kwargs(donate),
         )
         self._chaos = chaos
@@ -353,6 +385,7 @@ class BNNServer:
         self._valid_rows = 0
         self._real_rows = 0
         self._hbm_bytes = 0
+        self._flat_staged = 0
         self._inflight_n = 0
         self._inflight_peak = 0
         self._flight_faults = 0
@@ -401,7 +434,7 @@ class BNNServer:
             if self._fallback_jit is None:
                 fb = self.compiled.with_backend(self.fallback_backend)
                 self._fallback_jit = jax.jit(
-                    data_parallel(fb.apply, self.mesh),
+                    data_parallel(_restore_rows(fb.apply, self._row_shape), self.mesh),
                     **fb.serving_jit_kwargs(donate=False),
                 )
             return self._fallback_jit
@@ -412,6 +445,7 @@ class BNNServer:
         bucket: int,
         valid: int,
         owned: bool,
+        flat: bool,
         fallback: bool = False,
         flight: int = 0,
         first: bool = False,
@@ -422,12 +456,14 @@ class BNNServer:
         server-owned buffer: padding and placement create fresh ones,
         and the one aliasing case (exact-bucket rows arriving in a
         caller-held array) is defensively copied.  The fallback path
-        never donates at all (its jit has no donate_argnums).
+        never donates at all (its jit has no donate_argnums).  ``flat``:
+        the rows are host image rows staged as ``[rows, H*W*C]``
+        (``_stage``), which the jitted forward reshapes back.
 
         With the span recorder on, the chunk's host preparation and
-        host-to-device copy is a ``serve.stage`` span and the jit call
-        a ``serve.enqueue`` span (``first``: the level's first touch,
-        so the call traces and compiles or loads from the cache)."""
+        host-to-device copy is a ``serve.stage`` span (``flat``) and the
+        jit call a ``serve.enqueue`` span (``first``: the level's first
+        touch, so the call traces and compiles or loads from the cache)."""
         t0 = time.perf_counter() if spans.on else 0.0
         xp = _pad_rows(x, bucket)
         if fallback:
@@ -443,16 +479,25 @@ class BNNServer:
         out = fn(self.params, xs, valid_rows=valid)
         chunk = spans.new_id()
         spans.record("serve.stage", t0, t1, chunk, flight, bucket=bucket,
-                     valid=valid, bytes=_nbytes(xs))
+                     valid=valid, bytes=_nbytes(xs), flat=int(flat))
         spans.record("serve.enqueue", t1, time.perf_counter(), chunk, flight,
                      first=int(first))
         return out
 
     def _launch(
-        self, x: Any, rows: int, owned: bool, fallback: bool = False, flight: int = 0
+        self,
+        x: Any,
+        rows: int,
+        owned: bool,
+        kind: Tuple,
+        flat: bool,
+        fallback: bool = False,
+        flight: int = 0,
     ) -> Any:
         """Async-dispatch one micro-batch at its (bucket, valid) level;
         returns the UNRESOLVED output (``valid`` >= ``rows`` rows).
+        A level is keyed on the caller's payload ``kind`` and on
+        ``flat`` (rows staged flat are a program of their own).
 
         Only a level's FIRST dispatch holds the trace lock across the
         jit call (tracing happens inside the call, so concurrent first
@@ -466,17 +511,18 @@ class BNNServer:
         valid = ragged_valid(rows, bucket)
         hit: Optional[bool] = None
         if fallback:
-            out = self._run(x, bucket, valid, owned, fallback=True, flight=flight)
+            out = self._run(x, bucket, valid, owned, flat, fallback=True, flight=flight)
         else:
-            key = (bucket, valid, _kind_of(x))
+            key = (bucket, valid, kind, flat)
             with self._trace_lock:
                 hit = key in self._traced
                 if not hit:
                     self._warm(valid)
-                    out = self._run(x, bucket, valid, owned, flight=flight, first=True)
+                    out = self._run(x, bucket, valid, owned, flat, flight=flight,
+                                    first=True)
                     self._traced.add(key)
             if hit:
-                out = self._run(x, bucket, valid, owned, flight=flight)
+                out = self._run(x, bucket, valid, owned, flat, flight=flight)
         with self._stats_lock:
             if hit is True:
                 self._bucket_hits += 1
@@ -487,14 +533,23 @@ class BNNServer:
             self._valid_rows += valid
             self._real_rows += rows
             self._hbm_bytes += self._level_traffic(valid)
+            self._flat_staged += flat
         return out
 
     def _launch_chunks(
-        self, x: Any, rows: int, multi: bool, fallback: bool = False, flight: int = 0
+        self,
+        x: Any,
+        rows: int,
+        multi: bool,
+        kind: Tuple,
+        flat: bool,
+        fallback: bool = False,
+        flight: int = 0,
     ) -> List[Tuple[Any, int]]:
-        """Async-launch a payload as max_batch chunks + remainder;
-        returns [(unresolved out, chunk rows)].  ``multi``: the payload
-        was coalesced from several requests (already server-owned);
+        """Async-launch a staged payload (``_stage``) as max_batch
+        chunks + remainder; returns [(unresolved out, chunk rows)].
+        ``multi``: the payload was coalesced from several requests
+        (already server-owned); ``kind``: the callers' payload kind;
         ``flight``: the span id the chunks' spans name as parent."""
         outs: List[Tuple[Any, int]] = []
         chunks = split_rows(rows, self.max_batch)
@@ -502,9 +557,21 @@ class BNNServer:
         for chunk in chunks:
             piece = x if len(chunks) == 1 else _slice_rows(x, off, off + chunk)
             owned = multi or len(chunks) > 1
-            outs.append((self._launch(piece, chunk, owned, fallback, flight), chunk))
+            out = self._launch(piece, chunk, owned, kind, flat, fallback, flight)
+            outs.append((out, chunk))
             off += chunk
         return outs
+
+    def _stage(self, xs: Sequence[Any]) -> Tuple[Any, bool]:
+        """Coalesce request payloads into one batch; returns (batch,
+        flat).  Host image rows (``_flat_rows``) are staged flat, so
+        padding, coalescing and the host-to-device copy all move 2-D
+        rows, and the jitted forward restores NHWC (``_restore_rows``);
+        any other payload, or a mix, keeps its shape."""
+        flat = [_flat_rows(x, self._row_shape) for x in xs]
+        if all(f is not None for f in flat):
+            return _concat_rows(flat), True
+        return _concat_rows(xs), False
 
     def _finish_chunks(self, outs: List[Tuple[Any, int]]) -> Any:
         """Resolve launched chunks (block_until_ready) and reassemble
@@ -528,7 +595,9 @@ class BNNServer:
         bit-identical to ``compiled.apply(params, x)``."""
         rows = _rows_of(x)
         t0 = time.perf_counter()
-        out = self._finish_chunks(self._launch_chunks(x, rows, multi=False))
+        xs, flat = self._stage([x])
+        out = self._finish_chunks(
+            self._launch_chunks(xs, rows, False, _kind_of(x), flat))
         with self._stats_lock:
             self._n_requests += 1
             self._n_rows += rows
@@ -699,10 +768,10 @@ class BNNServer:
         (padding/coalescing stage into fresh server-owned buffers, and
         the fallback jit does not donate at all)."""
         self._chaos_flight(reqs, fallback)
-        x = _concat_rows([r.x for r in reqs])
+        x, flat = self._stage([r.x for r in reqs])
         rows = sum(r.rows for r in reqs)
         outs = self._launch_chunks(
-            x, rows, multi=len(reqs) > 1, fallback=fallback, flight=reqs[0].flight
+            x, rows, len(reqs) > 1, reqs[0].kind, flat, fallback, reqs[0].flight
         )
         return self._finish_chunks(outs)
 
@@ -815,7 +884,7 @@ class BNNServer:
         t_launch = time.perf_counter()
         try:
             self._chaos_flight(taken, False)
-            x = _concat_rows([r.x for r in taken])
+            x, flat = self._stage([r.x for r in taken])
             rows = sum(r.rows for r in taken)
             t_wait = time.perf_counter() if spans.on else 0.0
             self._ahead_sem.acquire()
@@ -828,9 +897,11 @@ class BNNServer:
                     bucket = bucket_for(rows, self.max_batch)
                     spans.record("serve.stage", t_launch, t_wait, spans.new_id(),
                                  flight, bucket=bucket,
-                                 valid=ragged_valid(rows, bucket), bytes=_nbytes(x))
+                                 valid=ragged_valid(rows, bucket), bytes=_nbytes(x),
+                                 flat=int(flat))
                 spans.record("serve.ahead_wait", t_wait, t_slot, flight)
-            outs = self._launch_chunks(x, rows, multi=len(taken) > 1, flight=flight)
+            outs = self._launch_chunks(x, rows, len(taken) > 1, taken[0].kind, flat,
+                                       flight=flight)
         except Exception as e:
             if acquired:
                 self._ahead_sem.release()
@@ -1067,9 +1138,10 @@ class BNNServer:
         """The serving counters (DESIGN.md §9/§10/§11 schema): request/
         row totals, dispatch and bucket-reuse counts, jit trace count
         vs the policy bound, padded-vs-valid-vs-real occupancy, HBM
-        bytes/request from the compiled traffic model, the in-flight
-        gauge, queue-wait / end-to-end latency percentiles, the
-        fault-recovery counters, and the straggler watchdog flags."""
+        bytes/request from the compiled traffic model, the chunks whose
+        host image rows were staged flat, the in-flight gauge,
+        queue-wait / end-to-end latency percentiles, the fault-recovery
+        counters, and the straggler watchdog flags."""
         with self._stats_lock:  # snapshot: writers hold the same locks
             lat = sorted(self._latencies)
             waits = sorted(self._queue_waits)
@@ -1079,6 +1151,7 @@ class BNNServer:
             padded, valid = self._padded_rows, self._valid_rows
             real = self._real_rows
             hbm = self._hbm_bytes
+            flat_staged = self._flat_staged
             inflight, inflight_peak = self._inflight_n, self._inflight_peak
             faults = {
                 "flights": self._flight_faults,
@@ -1093,7 +1166,7 @@ class BNNServer:
             straggler_flags = list(self._watchdog.flags)
             straggler_median = self._watchdog.median
         with self._trace_lock:
-            buckets = sorted({b for b, _, _ in self._traced})
+            buckets = sorted({key[0] for key in self._traced})
         dispatches = hits + misses
         stats = {
             "requests": requests,
@@ -1115,6 +1188,7 @@ class BNNServer:
             "compute_occupancy": real / valid if valid else 0.0,
             "hbm_bytes": hbm,
             "hbm_bytes_per_request": hbm / max(requests, 1),
+            "flat_staged": flat_staged,
             "devices": 1 if self.mesh is None else self.mesh.size,
             "faults": faults,
             "straggler_flags": straggler_flags,

@@ -17,9 +17,11 @@ import numpy as np
 import pytest
 
 from repro import graph
+from repro.core.workloads import ConvLayer, FCLayer, Workload
 from repro.kernels.autotune import get_table
 from repro.kernels.ops import binarize_pack
-from repro.serving import (BNNServer, bucket_for, bucket_sizes,
+from repro.robustness import ChaosMonkey
+from repro.serving import (BackendFault, BNNServer, bucket_for, bucket_sizes,
                            data_mesh, dispatch_grid, ensure_owned,
                            mask_levels, mask_step, pow2_ceil,
                            ragged_valid, split_rows, trace_bound)
@@ -41,6 +43,32 @@ def _mlp_server(max_batch=8, mesh=None, d0=256, hidden=(128, 64),
 def _packed(rng, rows, d0=256):
     x = jnp.asarray(rng.normal(size=(rows, d0)).astype(np.float32))
     return binarize_pack(x, backend="xla")
+
+
+def _conv_server(max_batch=8, mesh=None, **kw):
+    """A small image spec (8x8x3 integer entry conv, one binary conv
+    with pooling, two dense layers) behind a server."""
+    wl = Workload("tiny_conv", "tiny", (
+        ConvLayer("conv1", 3, 32, 8, 8, 8, 8, 3, integer=True),
+        ConvLayer("conv2", 32, 32, 8, 8, 4, 4, 3, integer=False),
+    ), (FCLayer("fc1", 512, 64), FCLayer("fc2", 64, 10)))
+    cb = graph.compile(wl, backend="xla", batch=4)
+    params = cb.init(jax.random.PRNGKey(0))
+    return cb, params, BNNServer(cb, params, max_batch=max_batch,
+                                 mesh=mesh, **kw)
+
+
+def _images(rng, rows):
+    """Host NHWC rows of 8-bit pixel values, as a client sends them."""
+    return rng.integers(0, 256, size=(rows, 8, 8, 3)).astype(np.float32)
+
+
+def _spy(fn, seen):
+    """``fn`` with the jit's call form, recording each input's rank."""
+    def apply(params, x, valid_rows=None):
+        seen.append(np.ndim(x))
+        return fn(params, x, valid_rows=valid_rows)
+    return apply
 
 
 # ------------------------------------------------------------------ #
@@ -282,6 +310,86 @@ def test_ensure_owned_copies_every_leaf():
 
 
 # ------------------------------------------------------------------ #
+# host image rows move flat; the forward restores NHWC                 #
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("path", ["full", "ragged", "coalesced",
+                                  "chunked", "fallback"])
+def test_host_image_rows_stage_flat(path):
+    """Host NHWC requests reach the jit as [rows, H*W*C] on every path
+    (an exact bucket, a padded one, several requests in one flight, an
+    oversized request cut in chunks, the fallback backend), one chunk
+    counted each; the answers equal ``compiled.apply`` bit for bit and
+    the caller's arrays are untouched."""
+    chaos = ChaosMonkey()
+    cb, params, srv = _conv_server(chaos=chaos)
+    seen = []
+    srv._apply_jit = _spy(srv._apply_jit, seen)
+    srv._fallback_fn()
+    srv._fallback_jit = _spy(srv._fallback_jit, seen)
+    rng = np.random.default_rng(14)
+    rows = {"full": [8], "ragged": [3], "coalesced": [2, 3, 3],
+            "chunked": [11], "fallback": [5]}[path]
+    xs = [_images(rng, r) for r in rows]
+    before = [x.copy() for x in xs]
+    if path == "fallback":
+        chaos.fail_next(BackendFault("kernel launch failed"))
+    futs = [srv.submit(x) for x in xs]
+    assert srv.flush() == 1
+    for fut, x, x0 in zip(futs, xs, before):
+        ref = np.asarray(cb.apply(params, jnp.asarray(x0)))
+        np.testing.assert_array_equal(np.asarray(fut.result(timeout=5)), ref)
+        np.testing.assert_array_equal(x, x0)
+    chunks = 2 if path == "chunked" else 1
+    assert seen == [2] * chunks
+    assert srv.stats()["flat_staged"] == chunks
+    if path == "fallback":
+        assert srv.stats()["faults"]["backend_fallbacks"] == 1
+
+
+def test_device_and_packed_requests_keep_their_shape():
+    """A device-resident jax.Array is not reshaped outside the jit (that
+    would add a device op), a flight that mixes it with host rows keeps
+    NHWC, and a PackedArray is already 2-D words: none is staged flat."""
+    cb, params, srv = _conv_server()
+    seen = []
+    srv._apply_jit = _spy(srv._apply_jit, seen)
+    rng = np.random.default_rng(15)
+    dev = jnp.asarray(_images(rng, 3))
+    host = _images(rng, 2)
+    np.testing.assert_array_equal(np.asarray(srv.apply_batch(dev)),
+                                  np.asarray(cb.apply(params, dev)))
+    futs = [srv.submit(dev), srv.submit(host)]
+    srv.flush()
+    np.testing.assert_array_equal(np.asarray(futs[1].result(timeout=5)),
+                                  np.asarray(cb.apply(params, host)))
+    assert seen == [4, 4]
+    assert srv.stats()["flat_staged"] == 0
+    mcb, mparams, msrv = _mlp_server()
+    xp = _packed(rng, 3)
+    np.testing.assert_array_equal(np.asarray(msrv.apply_batch(xp).words),
+                                  np.asarray(mcb.apply(mparams, xp).words))
+    assert msrv.stats()["flat_staged"] == 0
+
+
+def test_flat_staging_keeps_one_trace_per_level():
+    """Host rows staged flat take one jit trace per (bucket, valid)
+    level, as device-resident requests of the same rows do."""
+    rng = np.random.default_rng(16)
+    sizes = (8, 3, 5, 8, 3, 1)                      # levels (8,8) (4,3) (8,5) (1,1)
+    xs = [_images(rng, r) for r in sizes]
+    _, _, host_srv = _conv_server()
+    _, _, dev_srv = _conv_server()
+    for x in xs:
+        host_srv.apply_batch(x)
+        dev_srv.apply_batch(jnp.asarray(x))
+    assert host_srv.stats()["flat_staged"] == len(sizes)
+    assert dev_srv.stats()["flat_staged"] == 0
+    assert host_srv.jit_traces() == dev_srv.jit_traces() == 4
+    assert host_srv.stats()["buckets_traced"] == [1, 4, 8]
+    assert host_srv.stats()["bucket_hits"] == 2
+
+
+# ------------------------------------------------------------------ #
 # the continuously-batched queue                                       #
 # ------------------------------------------------------------------ #
 def test_queue_drain_bursty_arrival():
@@ -453,3 +561,17 @@ def test_sharded_binarynet_logits_bit_identical():
     got = srv.apply_batch(x)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     assert srv.jit_traces() <= 1
+
+
+@needs_mesh
+def test_sharded_host_image_rows_bit_identical():
+    """Host NHWC rows staged flat through a 4-virtual-device data mesh:
+    each device restores its own rows inside the sharded forward, and
+    the logits equal the single-device compiled apply exactly."""
+    cb, params, srv = _conv_server(mesh=data_mesh())
+    rng = np.random.default_rng(17)
+    for rows in (1, 3, 4, 8, 11):                   # incl. non-divisible
+        x = _images(rng, rows)
+        np.testing.assert_array_equal(np.asarray(srv.apply_batch(x)),
+                                      np.asarray(cb.apply(params, x)))
+    assert srv.stats()["flat_staged"] == 6          # 11 rows: two chunks

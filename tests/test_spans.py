@@ -111,6 +111,29 @@ def test_first_marks_a_levels_first_touch_only():
     assert firsts == [1, 0, 1]
 
 
+def test_stage_carries_flat():
+    """``serve.stage`` says whether the chunk's rows moved flat: host
+    NHWC image rows do, a PackedArray request does not."""
+    from repro.core.workloads import ConvLayer, FCLayer, Workload
+
+    wl = Workload("span_conv", "tiny", (
+        ConvLayer("conv1", 3, 32, 8, 8, 8, 8, 3, integer=True),
+        ConvLayer("conv2", 32, 32, 8, 8, 4, 4, 3, integer=False),
+    ), (FCLayer("fc1", 512, 64), FCLayer("fc2", 64, 10)))
+    cb = graph.compile(wl, backend="xla", batch=4)
+    conv = BNNServer(cb, cb.init(jax.random.PRNGKey(0)), max_batch=8)
+    _, mlp = _server()
+    rng = np.random.default_rng(4)
+    spans.enable()
+    _serve(conv, [rng.integers(0, 256, size=(3, 8, 8, 3)).astype(np.float32)])
+    (stage,) = _named(spans.collect(), "serve.stage")
+    assert stage[5]["flat"] == 1 and stage[5]["bytes"] == 4 * 8 * 8 * 3 * 4
+    spans.enable()
+    _serve(mlp, [_packed(rng, 3)])
+    (stage,) = _named(spans.collect(), "serve.stage")
+    assert stage[5]["flat"] == 0
+
+
 def test_backend_fault_records_recovery():
     rng = np.random.default_rng(3)
     chaos = ChaosMonkey()
