@@ -38,7 +38,7 @@ def control_reading(cell_name: str, seed: int) -> dict:
     rows = cfg["reference_block_rows"]
     want = reference.logits(cfg["layers"], raw, pool, rows)
     got = reference.logits(cfg["layers"], raw, pool, rows, precision="control")
-    gap, bad = run.logit_gaps(got, want)
+    gap, bad = run.logit_gaps(got, want, cfg["compare"].get("row_gap", 0.0))
     checks = run.checks_of(gap, bad, len(pool), 0, cfg["compare"])
     return {"kind": "control", "cell": cell_name, "seed": seed,
             "checks": checks, "correct": all(c["value"] <= c["limit"]

@@ -3,53 +3,73 @@
 This is the one module of the benchmark that imports the program:
 ``graph.compile`` -> served parameters -> ``BNNServer``.  The served
 parameters are the raw weights of bench/weights.py packed by the
-program's own packer, drawn and packed in one jitted call.
+program's own packer, drawn and packed in one jitted call.  Each
+layer's kind (bench/layers/<kind>.py) says what the program's model
+lists for it, where its weights go in the served tree, and which parts
+the plan's steps run.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Tuple
+import importlib
+from typing import Any, Dict, List
 
 import jax
 
 import weights
 from geometry import layer_shapes
+from kinds import BenchError, kind
 
 
-def _geometry(cfg: Dict[str, Any]) -> Tuple[tuple, tuple]:
-    """(conv rows, fc rows) of the layer table in the program's
-    ``core.workloads`` terms: (name, z1, z2, x1, y1, x2, y2, k, integer)
-    and (name, n_in, n_out)."""
-    conv, fc = [], []
-    for ly in layer_shapes(cfg):
-        if ly["kind"] == "dense":
-            fc.append((ly["name"], ly["n_in"], ly["n_out"]))
-        else:
-            conv.append((ly["name"], ly["c_in"], ly["c_out"], ly["w_in"],
-                         ly["h_in"], ly["w_out"], ly["h_out"], ly["k"],
-                         ly["kind"] == "entry_conv"))
-    return tuple(conv), tuple(fc)
+def _table_rows(cfg: Dict[str, Any]) -> Dict[str, List[tuple]]:
+    """The rows the program's model must list for the layer table, by
+    group, in table order."""
+    rows: Dict[str, List[tuple]] = {}
+    for sly in layer_shapes(cfg):
+        for group, row in kind(sly["kind"]).rows(sly):
+            rows.setdefault(group, []).append(row)
+    return rows
+
+
+def _listing(model) -> Dict[str, List[tuple]]:
+    """The model's own listing of its layers, as ``core.workloads``
+    rows: ``conv`` (name, z1, z2, x1, y1, x2, y2, k, integer) and ``fc``
+    (name, n_in, n_out), a BNNSpec's through the program's
+    ``spec_to_workload``."""
+    from repro.core.workloads import Workload
+    from repro.graph.ir import spec_to_workload
+
+    wl = model if isinstance(model, Workload) else spec_to_workload(model)
+    return {"conv": [(c.name, c.z1, c.z2, c.x1, c.y1, c.x2, c.y2, c.k, c.integer)
+                     for c in wl.conv],
+            "fc": [(f.name, f.n_in, f.n_out) for f in wl.fc]}
 
 
 def workload(cfg: Dict[str, Any]):
-    """The program's Workload for the configuration: its named builder
-    in ``repro.core.workloads``, checked against the layer table the
-    reference runs, or, without a builder, built from that table."""
+    """The program's model of the configuration (a ``Workload`` or a
+    ``BNNSpec``; ``graph.compile`` takes both): its ``builder``, a
+    function of ``repro.core.workloads`` by name or any program path
+    ``module:function``, checked against the rows of the layer table
+    the reference runs; without a builder, a Workload built from the
+    table's conv and fc rows."""
     from repro.core import workloads as W
 
-    conv, fc = _geometry(cfg)
+    table = _table_rows(cfg)
+    if set(table) - {"conv", "fc"}:
+        raise BenchError(f"{cfg['name']}: the program lists no rows "
+                         f"{sorted(set(table) - {'conv', 'fc'})}")
     builder = cfg.get("builder")
     if builder is None:
         return W.Workload(cfg["name"], cfg["name"],
-                          tuple(W.ConvLayer(*c) for c in conv),
-                          tuple(W.FCLayer(*f) for f in fc))
-    wl = getattr(W, builder)()
-    got = (tuple((c.name, c.z1, c.z2, c.x1, c.y1, c.x2, c.y2, c.k,
-                  c.integer) for c in wl.conv),
-           tuple((f.name, f.n_in, f.n_out) for f in wl.fc))
-    if got != (conv, fc):
-        raise ValueError(f"{builder}() does not match the layer table of "
-                         f"{cfg['name']}: {got} != {(conv, fc)}")
-    return wl
+                          tuple(W.ConvLayer(*c) for c in table.get("conv", ())),
+                          tuple(W.FCLayer(*f) for f in table.get("fc", ())))
+    mod, _, fn = builder.rpartition(":")
+    model = getattr(importlib.import_module(mod) if mod else W, fn)()
+    got = _listing(model)
+    want = {g: table.get(g, []) for g in got}
+    if got != want:
+        raise BenchError(f"{builder}() does not match the layer table of "
+                         f"{cfg['name']}: {got} != {want}")
+    return model
 
 
 def served_params(cfg: Dict[str, Any], seed: int) -> Dict[str, Any]:
@@ -61,18 +81,10 @@ def served_params(cfg: Dict[str, Any], seed: int) -> Dict[str, Any]:
     draw = weights.draw_fn(layers)
 
     def build(key):
-        params: Dict[str, List[Any]] = {"conv": [], "fc": []}
+        params: Dict[str, List[Any]] = {}
         for ly, p in zip(layers, draw(key)):
-            if ly["kind"] == "entry_conv":
-                params["conv"].append({"w": p["w"], "alpha": p["alpha"]})
-            elif ly["kind"] == "binary_conv":
-                params["conv"].append({"wf": PackedArray.pack(p["w"], axis=2),
-                                       "t": p["t"]})
-            else:
-                q = {"wp": PackedArray.pack(p["w"], axis=-1)}
-                if "t" in p:
-                    q["t"] = p["t"]
-                params["fc"].append(q)
+            group, entry = kind(ly["kind"]).served(ly, p, PackedArray.pack)
+            params.setdefault(group, []).append(entry)
         return params
 
     return jax.jit(build)(weights.weight_key(seed))
@@ -103,9 +115,12 @@ def build(cfg: Dict[str, Any], server_cfg: Dict[str, Any], chips: int,
 
 def plan_steps(cb, cfg: Dict[str, Any]) -> List[Dict[str, Any]]:
     """The compiled plan's steps as plain dicts the kernel counts read:
-    the step's kind and impl, with the shapes of the layers it runs."""
-    by_name = {ly["name"]: ly for ly in layer_shapes(cfg)}
-    dense = [ly for ly in layer_shapes(cfg) if ly["kind"] == "dense"]
+    the step's kind and impl, with the parts it runs, matched by name
+    over every layer's parts (a ``fused_stack`` runs the dense parts
+    its ``fc_indices`` name)."""
+    parts = [pt for sly in layer_shapes(cfg) for pt in kind(sly["kind"]).parts(sly)]
+    by_name = {pt["name"]: pt for pt in parts}
+    dense = [pt for pt in parts if pt["kind"] == "dense"]
     steps = []
     for s in cb.plan:
         if s.kind == "fused_stack":

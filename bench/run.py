@@ -16,7 +16,8 @@ time, kernels, device ops, idle gaps), and rates are taken over the
 untraced part before.
 
 Everything a cell is made of is found by name: the cell in
-BENCHMARK.json, its configuration file, its traffic mix in
+BENCHMARK.json, its configuration file, each layer kind of that file's
+table in bench/layers/<kind>.py, its traffic mix in
 bench/traffic/<traffic>.json, its server settings in
 bench/workloads/<cell>.json, each metric's reader in
 bench/metrics/<metric>.py and each kernel's operation and byte count
@@ -33,7 +34,6 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -47,6 +47,8 @@ sys.path.insert(1, os.path.join(ROOT, "src"))
 
 import numpy as np  # noqa: E402
 
+from kinds import BenchError, load_module  # noqa: E402
+
 BENCHMARK_JSON = os.path.join(ROOT, "BENCHMARK.json")
 TRAFFIC_DIR = os.path.join(BENCH, "traffic")
 WORKLOADS_DIR = os.path.join(BENCH, "workloads")
@@ -55,21 +57,9 @@ COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/compilation_cache/cache_retrieval_time_sec")
 
 
-class BenchError(RuntimeError):
-    """A run that cannot produce a result."""
-
-
 def _load_json(path: str) -> Dict[str, Any]:
     with open(path) as f:
         return json.load(f)
-
-
-def load_module(path: str):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + os.path.basename(path)[:-3].replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def reader_name(metric: str) -> str:
@@ -202,11 +192,12 @@ def warm_rows(mix: Dict[str, Any], max_batch: int) -> List[int]:
     return [max_batch] if fixed else list(range(1, max_batch + 1))
 
 
-def logit_gaps(got: np.ndarray, want: np.ndarray):
-    """(widest |got - want|, rows with any gap) of one block of logits."""
+def logit_gaps(got: np.ndarray, want: np.ndarray, row_gap: float = 0.0):
+    """(widest |got - want|, rows whose widest gap exceeds ``row_gap``)
+    of one block of logits."""
     d = np.abs(got.astype(np.float64) - want)
     d[~np.isfinite(d)] = np.inf
-    return float(d.max(initial=0.0)), int(np.count_nonzero(d.max(axis=1) > 0))
+    return float(d.max(initial=0.0)), int(np.count_nonzero(d.max(axis=1) > row_gap))
 
 
 def checks_of(gap: float, rows_bad: int, rows_all: int, failed: int,
@@ -222,10 +213,12 @@ def checks_of(gap: float, rows_bad: int, rows_all: int, failed: int,
 def compare(cfg, seed: int, rec, pool: np.ndarray) -> Dict[str, Dict[str, float]]:
     """The numbers compared and their limits: the widest gap between a
     served logit and the reference's, and the share of answered rows
-    with any gap, over every answer in the run; and the requests that
-    failed or never answered."""
+    whose widest gap exceeds the configuration's ``compare.row_gap``
+    (default 0: any gap), over every answer in the run; and the
+    requests that failed or never answered."""
     import reference
     import weights
+    from geometry import final_shape
 
     raw = weights.make_raw(cfg["layers"], seed)
     used = np.zeros(len(pool), bool)
@@ -233,16 +226,16 @@ def compare(cfg, seed: int, rec, pool: np.ndarray) -> Dict[str, Dict[str, float]
         if o is not None:
             used[off:off + n] = True
     idx = np.flatnonzero(used)
-    want = np.zeros((len(pool), cfg["layers"][-1]["n_out"]), np.float32)
+    want = np.zeros((len(pool), *final_shape(cfg)), np.float32)
     if idx.size:
         want[idx] = reference.logits(cfg["layers"], raw, pool[idx],
                                      cfg["reference_block_rows"])
     gap, rows_bad, rows_all, failed = 0.0, 0, 0, 0
     for o, off, n in zip(rec.out, rec.off, rec.rows):
-        if o is None or o.shape != (n, want.shape[1]):
+        if o is None or o.shape != (n, *want.shape[1:]):
             failed += 1
             continue
-        g, b = logit_gaps(o, want[off:off + n])
+        g, b = logit_gaps(o, want[off:off + n], cfg["compare"].get("row_gap", 0.0))
         gap, rows_bad, rows_all = max(gap, g), rows_bad + b, rows_all + n
     return checks_of(gap, rows_bad, rows_all, failed, cfg["compare"])
 

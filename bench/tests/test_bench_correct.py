@@ -18,23 +18,6 @@ DATA = os.path.join(os.path.dirname(__file__), "data")
 SEED = 2**31 + 4242
 
 
-@pytest.fixture
-def on_cpu(monkeypatch, tmp_path):
-    """The harness without its look for a chip: the test cells of
-    data/bench.json on the CPU's devices with the v5e's peaks, and the
-    compile cache in a directory of the test's own."""
-    import jax
-    from repro.launch import cache
-
-    monkeypatch.setattr(run, "BENCHMARK_JSON", os.path.join(DATA, "bench.json"))
-    monkeypatch.setattr(run, "TRAFFIC_DIR", os.path.join(DATA, "traffic"))
-    monkeypatch.setattr(run, "WORKLOADS_DIR", os.path.join(DATA, "workloads"))
-    monkeypatch.setattr(cache, "CACHE_DIR", str(tmp_path / "jax_cache"))
-    peaks = run._load_json(os.path.join(run.BENCH, "peaks.json"))["kinds"]["TPU v5 lite"]
-    monkeypatch.setattr(run, "chips_for", lambda n: (jax.devices()[:n], peaks))
-    return monkeypatch
-
-
 def _broken(monkeypatch, fault):
     """Build the server as the run does, with ``fault`` applied to every
     answer where the compiled program produces it."""

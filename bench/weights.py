@@ -5,11 +5,10 @@ served parameters (bench/program.py packs them through the program's
 own packer) and the plain reference (bench/reference.py), so the
 reference takes nothing the program made.
 
-Weights are drawn on the device in one jitted call: a float32 normal
-latent weight per layer (the sign is the binary weight), the entry
-layers' per-channel scale alpha = mean |w|, and an integer threshold in
-[-3, 3] per output channel of every thresholded layer.  Images are
-8-bit pixel values held as float32, drawn on the host.
+Weights are drawn on the device in one jitted call: the key is split
+into one key per layer, and each layer's kind (bench/layers/<kind>.py)
+draws that layer's float32 weights from its key.  Images are 8-bit
+pixel values held as float32, drawn on the host.
 """
 from __future__ import annotations
 
@@ -18,6 +17,8 @@ from typing import Any, Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from kinds import kind
 
 THRESHOLD_RANGE = 3
 
@@ -29,40 +30,22 @@ def seed_words(seed: int, salt: int = 0) -> np.ndarray:
                                   ).generate_state(2, dtype=np.uint32)
 
 
-def weight_shapes(layers: List[Dict[str, Any]]) -> List[tuple]:
-    """Latent weight shape of each layer: HWIO for convs, [N, K] for
-    dense layers."""
-    out = []
-    for ly in layers:
-        if ly["kind"] == "dense":
-            out.append((ly["n_out"], ly["n_in"]))
-        else:
-            out.append((ly["k"], ly["k"], ly["c_in"], ly["c_out"]))
-    return out
+def thresholds(key: jax.Array, n: int) -> jax.Array:
+    """``n`` integer thresholds, uniform in [-3, 3] (int32): the stand-in
+    for a folded batch norm."""
+    return jax.random.randint(key, (n,), -THRESHOLD_RANGE,
+                              THRESHOLD_RANGE + 1, jnp.int32)
 
 
 def draw_fn(layers: List[Dict[str, Any]]):
-    """The pure function key -> per-layer ``{"w", "alpha"?, "t"?}``;
-    ``make_raw`` jits it, and bench/program.py jits it together with
-    the packing into served parameters."""
-    shapes = weight_shapes(layers)
-    kinds = tuple((ly["kind"], ly.get("threshold", True)) for ly in layers)
-    out_ch = tuple(s[0] if k == "dense" else s[-1]
-                   for s, (k, _) in zip(shapes, kinds))
+    """The pure function key -> each layer's raw weights; ``make_raw``
+    jits it, and bench/program.py jits it together with the packing
+    into served parameters."""
+    mods = [kind(ly["kind"]) for ly in layers]
 
     def draw(key):
-        ks = jax.random.split(key, len(shapes))
-        raw = []
-        for kk, shape, (kind, thr), n in zip(ks, shapes, kinds, out_ch):
-            kw, kt = jax.random.split(kk)
-            p = {"w": jax.random.normal(kw, shape, jnp.float32)}
-            if kind == "entry_conv":
-                p["alpha"] = jnp.mean(jnp.abs(p["w"]), axis=(0, 1, 2))
-            elif thr:
-                p["t"] = jax.random.randint(kt, (n,), -THRESHOLD_RANGE,
-                                            THRESHOLD_RANGE + 1, jnp.int32)
-            raw.append(p)
-        return raw
+        ks = jax.random.split(key, len(layers))
+        return [m.draw(kk, ly) for m, ly, kk in zip(mods, layers, ks)]
 
     return draw
 
