@@ -16,12 +16,16 @@ from repro.graph.compile import (CompiledBNN, compile,
                                  serve_folded_stack)
 from repro.graph.ir import (Binarize, BinaryConv, BinaryDense, BNNSpec,
                             BNThreshold, IntegerEntry, Logits, MaxPool,
+                            PoolFC, ResidualBlock, ResidualEntry,
                             from_dense_stack, from_workload,
+                            reactnet_a_imagenet, residual_net,
                             spec_to_workload)
 from repro.graph.passes import PlanStep, build_plan
 
 __all__ = ["Binarize", "BinaryConv", "BinaryDense", "BNNSpec",
            "BNThreshold", "CompiledBNN", "IntegerEntry", "Logits",
-           "MaxPool", "PlanStep", "build_plan", "compile",
+           "MaxPool", "PlanStep", "PoolFC", "ResidualBlock",
+           "ResidualEntry", "build_plan", "compile",
            "compile_dense_stack", "from_dense_stack", "from_workload",
-           "serve_folded_stack", "spec_to_workload"]
+           "reactnet_a_imagenet", "residual_net", "serve_folded_stack",
+           "spec_to_workload"]

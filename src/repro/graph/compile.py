@@ -24,9 +24,14 @@ Pipeline (see graph/passes.py for passes 2-5):
 The legacy builders (models.layers.packed_cnn_*, packed_mlp,
 core.bnn_layers.bnn_mlp_serve_folded) are thin deprecated shims over
 this entry point.
+
+A residual spec (graph/ir.py ResidualEntry / ResidualBlock / PoolFC)
+carries ``(stream, packed bits or int32 sums)`` from step to step: the
+float32 stream beside the packed input of the next binary conv.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -42,13 +47,16 @@ from repro.core.mapping import (TULIP, YODANN, ArchParams, map_conv,
 from repro.core.schedules import compare_fragment, maxpool_fragment
 from repro.core.workloads import Workload
 from repro.graph.ir import (BinaryConv, BinaryDense, BNNSpec,
-                            IntegerEntry, MaxPool, from_dense_stack,
-                            from_workload, spec_to_workload)
+                            IntegerEntry, MaxPool, PoolFC, ResidualBlock,
+                            from_dense_stack, from_workload,
+                            spec_to_workload)
 from repro.graph.passes import (PlanStep, batches_tuning_keys, build_plan,
                                 plan_tuning_keys)
 from repro.kernels import ops as kops
 from repro.kernels.fused_mlp import fused_binary_mlp
 from repro.kernels.packed import PackedArray
+from repro.kernels.residual import (avg_pool2, border_classes,
+                                    border_table, epilogue_bytes)
 from repro.runtime import spans
 
 __all__ = ["CompiledBNN", "compile", "compile_dense_stack",
@@ -59,6 +67,45 @@ def _maxpool_float(x: jax.Array, window: int, stride: int) -> jax.Array:
     return jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, window, window, 1),
         (1, stride, stride, 1), "VALID")
+
+
+def _global_mean(x: jax.Array) -> jax.Array:
+    """Global average pool of NHWC ``x``: the H*W positions summed in
+    row-major order, times 1 / (H*W)."""
+    n, hh, ww, c = x.shape
+    flat = x.reshape(n, hh * ww, c)
+    acc = flat[:, 0]
+    for i in range(1, hh * ww):
+        acc = acc + flat[:, i]
+    return acc * (1.0 / (hh * ww))
+
+
+# a residual block's per-channel parameters of each epilogue, in
+# kernels.residual.VEC_ROWS order (the last row, the bias of the next
+# sign, is the next conv's)
+_EPILOGUE_PARAMS = {1: ("bn1_scale", "bn1_bias", "move12", "prelu1",
+                        "move13"),
+                    2: ("bn2_scale", "bn2_bias", "move22", "prelu2",
+                        "move23")}
+
+
+def _bn_scale(key: jax.Array, n: int, fan: float) -> jax.Array:
+    """Inference-time BN scales U[0.5, 1.5] / fan, rounded to bfloat16:
+    8 significant bits times an integer sum below 2**16 is exact in
+    float32, so the scale-and-bias gives the same bits whether or not
+    a compiler fuses the multiply into the add."""
+    k = jax.random.uniform(key, (n,), jnp.float32, 0.5, 1.5) / fan
+    return k.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def _bias(key: jax.Array, n: int) -> jax.Array:
+    return 0.1 * jax.random.normal(key, (n,), jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _border_table(wf: PackedArray, h: int, w: int, stride: int,
+                  pad: int) -> jax.Array:
+    return border_table(wf, h, w, stride, pad)[0]
 
 
 def _bind_dense(p: Dict[str, Any]) -> Tuple[PackedArray, Any]:
@@ -101,17 +148,22 @@ class CompiledBNN:
                 f"(legacy chain: {self.legacy_launch_count()})")
         return "\n".join([head] + [f"  {s}" for s in self.plan])
 
+    # plan steps that are one kernel launch each
+    _LAUNCHES = ("binarize", "binary_conv", "dense", "residual_entry",
+                 "residual_conv", "residual_epilogue")
+
     def launch_count(self) -> int:
         """Kernel launches per forward pass under this plan (the
-        integer-entry XLA convs and reshapes don't count)."""
-        return sum(s.kind in ("binarize", "binary_conv", "dense",
-                              "fused_stack") for s in self.plan)
+        integer-entry XLA convs, the float head and reshapes don't
+        count; a residual block is four: two convs, two epilogues)."""
+        return sum(s.kind in self._LAUNCHES + ("fused_stack",)
+                   for s in self.plan)
 
     def legacy_launch_count(self) -> int:
         """What the legacy layer-by-layer builder chain would launch:
         every fused_stack segment unrolls to one launch per layer."""
         return sum(len(s.args["fc_indices"]) if s.kind == "fused_stack"
-                   else s.kind in ("binarize", "binary_conv", "dense")
+                   else s.kind in self._LAUNCHES
                    for s in self.plan)
 
     def tuning_keys_for_batch(self, batch: int) -> Tuple[tuple, ...]:
@@ -191,6 +243,20 @@ class CompiledBNN:
                               max_batch=max_batch).raise_if_failed()
 
     # -------------------------------------------------------------- #
+    def bind(self, params: Dict[str, Any]) -> Dict[str, Any]:
+        """The parameter tree as ``apply`` reads it, prepared once, not
+        per forward: each ResidualBlock's entry gains ``border``, the
+        zero-border table of its 3x3 conv (kernels.residual
+        .border_table of the packed ``w1``).  A spec without residual
+        blocks is returned as it is.  ``init`` and ``BNNServer`` bind."""
+        blocks = self.spec.residual_blocks
+        if not blocks:
+            return params
+        res = [dict(p, border=_border_table(p["w1"], nd.h_in, nd.w_in,
+                                            nd.stride, 1))
+               for nd, p in zip(blocks, params["res"])]
+        return dict(params, res=res)
+
     def init(self, key: jax.Array, threshold_range: int = 3,
              dtype: Any = jnp.float32) -> Dict[str, Any]:
         """Random packed serving parameters for the spec — key-split
@@ -199,6 +265,8 @@ class CompiledBNN:
         alpha; binary convs hold channel-packed filters + per-channel
         int32 thresholds standing in for folded BN; dense layers hold
         [N, K] PackedArrays, thresholded ones a ``t`` vector)."""
+        if self.spec.residual_blocks:
+            return self._init_residual(key)
         conv_nodes = self.spec.conv_nodes
         dense_nodes = self.spec.dense_nodes
         thresholded = [self.spec.thresholded(n) for n in dense_nodes]
@@ -227,6 +295,53 @@ class CompiledBNN:
                     -threshold_range, threshold_range + 1, jnp.int32)
             params["fc"].append(p)
         return params
+
+    def _init_residual(self, key: jax.Array) -> Dict[str, Any]:
+        """Random parameters of a residual spec: ``conv`` holds the
+        entry conv (latent ``w``, BN scale ``alpha`` and bias
+        ``beta``), ``res`` one dict per ResidualBlock (packed ``w1``
+        [3, 3, C, C] and ``w2`` [1, 1, C, C_out], the RSign biases
+        ``rsign1``/``rsign2`` and each epilogue's ``_EPILOGUE_PARAMS``),
+        ``head`` the PoolFC dense (``w`` [n_in, classes], ``b``).  BN
+        keeps each branch O(1): scales U[0.5, 1.5] / sqrt(fan-in)
+        (bfloat16, ``_bn_scale``; the entry conv's over 128 x its
+        fan-in, for 8-bit pixels), biases N(0, 0.1), PReLU slopes
+        U[0.1, 0.3], dense weights N(0, 1 / n_in)."""
+        stem = self.spec.conv_nodes[0]
+        blocks = self.spec.residual_blocks
+        head = next(n for n in self.spec.nodes if isinstance(n, PoolFC))
+        ks = jax.random.split(key, len(blocks) + 2)
+        k = jax.random.split(ks[0], 3)
+        fan0 = stem.kh * stem.kw * stem.c_in
+        params: Dict[str, Any] = {"conv": [{
+            "w": jax.random.normal(k[0], (stem.kh, stem.kw, stem.c_in,
+                                          stem.c_out), jnp.float32),
+            "alpha": _bn_scale(k[1], stem.c_out, 128.0 * fan0 ** 0.5),
+            "beta": _bias(k[2], stem.c_out)}], "res": [], "head": []}
+        for nd, kb in zip(blocks, ks[1:]):
+            c, f = nd.c_in, nd.c_out
+            k = jax.random.split(kb, 14)
+            p = {"w1": PackedArray.pack(jax.random.normal(
+                     k[0], (3, 3, c, c), jnp.float32), axis=2),
+                 "w2": PackedArray.pack(jax.random.normal(
+                     k[1], (1, 1, c, f), jnp.float32), axis=2),
+                 "rsign1": _bias(k[2], c), "rsign2": _bias(k[3], c)}
+            for part, n, fan, kk in ((1, c, 9 * c, k[4:9]),
+                                     (2, f, c, k[9:14])):
+                scale, bias, m_in, slope, m_out = _EPILOGUE_PARAMS[part]
+                p[scale] = _bn_scale(kk[0], n, fan ** 0.5)
+                p[bias] = _bias(kk[1], n)
+                p[m_in] = _bias(kk[2], n)
+                p[slope] = jax.random.uniform(kk[3], (n,), jnp.float32,
+                                              0.1, 0.3)
+                p[m_out] = _bias(kk[4], n)
+            params["res"].append(p)
+        k = jax.random.split(ks[-1])
+        params["head"].append({
+            "w": jax.random.normal(k[0], (head.n_in, head.classes),
+                                   jnp.float32) / head.n_in ** 0.5,
+            "b": _bias(k[1], head.classes)})
+        return self.bind(params)
 
     # -------------------------------------------------------------- #
     def apply(self, params: Dict[str, Any], x: Any,
@@ -303,9 +418,49 @@ class CompiledBNN:
                 pack_out=a["pack_out"], backend=be)
         elif step.kind == "logits":
             h = h.astype(jnp.float32)
+        elif step.kind == "residual_entry":
+            x = h + params["conv"][a["conv_idx"]]["beta"]
+            h = (x, kops.binarize_pack(x + params["res"][0]["rsign1"],
+                                       backend=be))
+        elif step.kind == "residual_conv":
+            x, bits = h
+            w = params["res"][a["res_idx"]][a["weight"]]
+            h = (x, kops.binary_conv2d(bits, w, stride=a["stride"],
+                                       padding=a["pad"], backend=be,
+                                       impl=a["impl"]))
+        elif step.kind == "residual_epilogue":
+            h = self._residual_epilogue(a, params, *h)
+        elif step.kind == "pool_fc":
+            p = params["head"][0]
+            h = jnp.dot(_global_mean(h[0]), p["w"],
+                        precision=jax.lax.Precision.HIGHEST) + p["b"]
         else:                      # pragma: no cover
             raise AssertionError(f"unknown plan step {step.kind}")
         return h
+
+    def _residual_epilogue(self, a: dict, params: Dict[str, Any],
+                           x: jax.Array, s: jax.Array):
+        """One ``residual_epilogue`` step: the block's per-channel rows,
+        the next conv's sign bias (none after the last block), the
+        zero-border table of a 3x3 conv, and the shortcut."""
+        res = params["res"]
+        p = res[a["res_idx"]]
+        if not a["next"]:
+            nxt = jnp.zeros((s.shape[-1],), jnp.float32)
+        elif a["part"] == 1:
+            nxt = p["rsign2"]
+        else:
+            nxt = res[a["res_idx"] + 1]["rsign1"]
+        vec = jnp.stack([p[k] for k in _EPILOGUE_PARAMS[a["part"]]]
+                        + [nxt]).astype(jnp.float32)
+        table = rows = None
+        if a["border"] is not None:
+            hh, ww, k, stride, pad = a["border"]
+            table = p["border"]
+            rows = border_classes(hh, ww, k, stride, pad)[0]
+        sc = avg_pool2(x) if a["pool"] else x
+        return kops.residual_epilogue(s, sc, vec, table, rows,
+                                      pack=a["next"], backend=self.backend)
 
     # -------------------------------------------------------------- #
     def traffic(self, batch: int = 1) -> Dict[str, Any]:
@@ -331,11 +486,61 @@ class CompiledBNN:
             layers.append({"name": nd.name,
                            "packed_bytes": n_in // 8 + n_w // 8,
                            "bf16_bytes": 2 * n_in + 2 * n_w})
+        layers += self._residual_traffic(batch)
         packed = sum(d["packed_bytes"] for d in layers)
         bf16 = sum(d["bf16_bytes"] for d in layers)
         return {"layers": layers, "packed_bytes": packed,
                 "bf16_bytes": bf16,
                 "ratio_bf16_over_packed": bf16 / packed}
+
+    def _residual_traffic(self, batch: int) -> List[dict]:
+        """Per step of each ResidualBlock: a conv moves its packed input
+        and weights and writes int32 sums; an epilogue reads the sums,
+        the float32 shortcut, its [6, C] rows and (3x3) the int32
+        border table, and writes the float32 stream and (unless last)
+        the packed signs; a stride-2 shortcut is pooled first.  The
+        entry reads the conv's output and writes the stream and its
+        packed signs; the head reads the stream and its float32 dense.
+        The bf16 baseline moves every value at 2 bytes."""
+        layers: List[dict] = []
+        blocks = self.spec.residual_blocks
+
+        def add(name, packed, values):
+            layers.append({"name": name, "packed_bytes": int(packed),
+                           "bf16_bytes": int(2 * values)})
+
+        if blocks:
+            b0 = blocks[0]
+            v = batch * b0.h_in * b0.w_in * b0.c_in
+            add("stem.bn", 8 * v + 4 * b0.c_in + v // 8, 3 * v)
+        for i, nd in enumerate(blocks):
+            c, f = nd.c_in, nd.c_out
+            m_in = batch * nd.h_in * nd.w_in
+            m = batch * nd.h_out * nd.w_out
+            for name, px, k, cin, cout in ((".conv3x3", m_in, 3, c, c),
+                                           (".conv1x1", m, 1, c, f)):
+                w = k * k * cin * cout
+                add(nd.name + name, px * cin // 8 + w // 8 + 4 * m * cout,
+                    px * cin + w + m * cout)
+                if name == ".conv3x3":
+                    if nd.stride == 2:
+                        add(nd.name + ".shortcut", 4 * m_in * c + 4 * m * c,
+                            m_in * c + m * c)
+                    _, _, rsets, _ = border_classes(
+                        nd.h_in, nd.w_in, 3, nd.stride, 1)
+                    add(nd.name + ".epilogue1",
+                        epilogue_bytes(m, nd.w_out, c, c, True,
+                                       len(rsets)), 4 * m * c)
+            last = i == len(blocks) - 1
+            add(nd.name + ".epilogue2",
+                epilogue_bytes(m, nd.w_out, f, c, not last, None),
+                (3 * f + c) * m)
+        for nd in self.spec.nodes:
+            if isinstance(nd, PoolFC):
+                v = batch * blocks[-1].h_out * blocks[-1].w_out * nd.n_in
+                add(nd.name, 4 * v + 4 * nd.n_in * nd.classes
+                    + 4 * nd.classes, v + nd.n_in * nd.classes)
+        return layers
 
     # -------------------------------------------------------------- #
     def tulip_mapping(self, arch: ArchParams = TULIP) -> List[dict]:
@@ -348,14 +553,16 @@ class CompiledBNN:
         rows: List[dict] = []
         conv_i = fc_i = 0
         for nd in self.spec.nodes:
-            if isinstance(nd, (IntegerEntry, BinaryConv)):
-                m = map_conv(wl.conv[conv_i], arch)
-                conv_i += 1
-                rows.append({"node": nd.name, "kind": "conv",
-                             "mapping": m,
-                             "cmp_cycles": _cmp_cycles(m.node_inputs)
-                             if m.uses_pe else None})
-            elif isinstance(nd, BinaryDense):
+            if isinstance(nd, (IntegerEntry, BinaryConv, ResidualBlock)):
+                for _ in range(2 if isinstance(nd, ResidualBlock) else 1):
+                    cl = wl.conv[conv_i]
+                    m = map_conv(cl, arch)
+                    conv_i += 1
+                    rows.append({"node": cl.name, "kind": "conv",
+                                 "mapping": m,
+                                 "cmp_cycles": _cmp_cycles(m.node_inputs)
+                                 if m.uses_pe else None})
+            elif isinstance(nd, (BinaryDense, PoolFC)):
                 m = map_fc(wl.fc[fc_i], arch)
                 fc_i += 1
                 rows.append({"node": nd.name, "kind": "dense",

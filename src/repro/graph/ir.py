@@ -18,6 +18,18 @@ Node set:
                  always FUSED into its producer's pack epilogue
   Logits         int32 dot -> float32 logits (the classifier output)
 
+The float residual domain (a residual BNN such as ReActNet-A,
+arXiv:2003.03488, DESIGN.md §8):
+  ResidualEntry  the entry conv's BN bias: opens a float32 stream that
+                 lives beside the 1-bit convs
+  ResidualBlock  one composite block of the stream: sign(x + b) packed,
+                 a zero-padded 3x3 binary conv (stride 1 or 2), its BN,
+                 the shortcut add (x, or a 2x2 average pool of x at
+                 stride 2), RPReLU, then the same around a 1x1 binary
+                 conv whose output width is C or 2C (two halves that
+                 both add the stream); lowered to four plan steps
+  PoolFC         global average pool + float dense -> float32 logits
+
 Lowering entry points:
   from_workload     core/workloads.py dataclass -> BNNSpec (subsumes
                     the geometry inference that used to live in
@@ -32,7 +44,13 @@ must match, the packed domain can only be left through Logits, integer
 layers cannot follow binary ones (a 1-bit activation cannot re-enter
 the float domain — the same "not representable" rule the legacy
 builder enforced), and every non-terminal BinaryConv/BinaryDense must
-be thresholded (an int32 activation cannot stay packed).
+be thresholded (an int32 activation cannot stay packed).  The residual
+domain has rules of its own: only ResidualEntry opens it, directly
+after an IntegerEntry of the same width; inside it only ResidualBlock
+nodes follow, each reading the stream's exact shape (C a multiple of
+32, output C or 2C, stride 2 only on even maps); and only PoolFC,
+the terminal node, leaves it.  No packed-domain node reads the stream
+and no residual node reads packed input.
 """
 from __future__ import annotations
 
@@ -43,9 +61,11 @@ from typing import Optional, Sequence, Tuple, Union
 from repro.core.workloads import ConvLayer, FCLayer, Workload
 
 __all__ = ["Binarize", "BinaryConv", "BinaryDense", "BNNSpec",
-           "BNThreshold", "IntegerEntry", "Logits", "MaxPool",
+           "BNThreshold", "IntegerEntry", "Logits", "MaxPool", "PoolFC",
+           "REACTNET_A_BLOCKS", "ResidualBlock", "ResidualEntry",
            "fc_entry_size", "from_dense_stack", "from_workload",
-           "infer_conv_geometry", "infer_pool", "spec_to_workload"]
+           "infer_conv_geometry", "infer_pool", "reactnet_a_imagenet",
+           "residual_net", "spec_to_workload"]
 
 
 # ------------------------------------------------------------------ #
@@ -171,8 +191,53 @@ class Logits:
     classes: int
 
 
+@dataclass(frozen=True)
+class ResidualEntry:
+    """Opens the float residual stream after an IntegerEntry: adds the
+    entry conv's per-channel BN bias (held with that conv's parameters
+    as ``beta``; its ``alpha`` is the BN scale)."""
+    name: str
+    channels: int
+
+
+@dataclass(frozen=True)
+class ResidualBlock:
+    """One ReActNet block over the float stream x [h_in, w_in, c_in]:
+
+    a = sign(x + rsign1); u = RPReLU1(BN1(conv3x3_s(a)) + sc), sc = x
+    or avgpool2x2(x) at stride 2; b = sign(u + rsign2); out =
+    RPReLU2(BN2(conv1x1(b)) + u), the 1x1 conv c_in -> c_out with
+    c_out = c_in, or 2 * c_in as two halves that each add u.  The 3x3
+    conv pads with zeros (an exact integer correction of the kernels'
+    -1 border, kernels/residual.py); RPReLU(v) = PReLU(v + move_in;
+    slope) + move_out, per channel."""
+    name: str
+    c_in: int
+    c_out: int
+    h_in: int
+    w_in: int
+    stride: int = 1
+
+    @property
+    def h_out(self) -> int:
+        return (self.h_in - 1) // self.stride + 1
+
+    @property
+    def w_out(self) -> int:
+        return (self.w_in - 1) // self.stride + 1
+
+
+@dataclass(frozen=True)
+class PoolFC:
+    """Terminal of the residual stream: global average pool, then a
+    float32 dense n_in -> classes with a bias (the logits)."""
+    name: str
+    n_in: int
+    classes: int
+
+
 Node = Union[IntegerEntry, Binarize, BinaryConv, MaxPool, BinaryDense,
-             BNThreshold, Logits]
+             BNThreshold, Logits, ResidualEntry, ResidualBlock, PoolFC]
 ConvNode = (IntegerEntry, BinaryConv)
 
 
@@ -200,6 +265,11 @@ class BNNSpec:
         return tuple(n for n in self.nodes
                      if isinstance(n, BinaryDense))
 
+    @property
+    def residual_blocks(self) -> Tuple[ResidualBlock, ...]:
+        return tuple(n for n in self.nodes
+                     if isinstance(n, ResidualBlock))
+
     def thresholded(self, node: Union[BinaryConv, BinaryDense]) -> bool:
         """True when ``node`` is directly followed by a BNThreshold."""
         i = next((j for j, n in enumerate(self.nodes) if n is node),
@@ -226,7 +296,55 @@ class BNNSpec:
         width = self.input_shape[0] if first_dense else 0
         for i, nd in enumerate(self.nodes):
             prev = self.nodes[i - 1] if i else None
-            if isinstance(nd, IntegerEntry):
+            if domain == "residual" and not isinstance(
+                    nd, (ResidualBlock, PoolFC)):
+                raise ValueError(
+                    f"{nd.name}: the residual stream can only be read by "
+                    f"ResidualBlock nodes and left through PoolFC")
+            if isinstance(nd, ResidualEntry):
+                if not isinstance(prev, IntegerEntry):
+                    raise ValueError(
+                        f"{nd.name}: the residual stream opens directly "
+                        f"after an IntegerEntry")
+                if nd.channels != c:
+                    raise ValueError(f"{nd.name}: {nd.channels} channels "
+                                     f"for a {c}-wide entry conv")
+                domain = "residual"
+            elif isinstance(nd, ResidualBlock):
+                if domain != "residual":
+                    raise ValueError(
+                        f"{nd.name}: a residual block reads the float "
+                        f"residual stream, not {domain} input (open the "
+                        f"stream with ResidualEntry)")
+                if (nd.c_in, nd.h_in, nd.w_in) != (c, h, w):
+                    raise ValueError(
+                        f"{nd.name}: expects {nd.h_in}x{nd.w_in}x"
+                        f"{nd.c_in}, the stream is {h}x{w}x{c}")
+                if nd.c_in % 32 or nd.c_out not in (nd.c_in, 2 * nd.c_in):
+                    raise ValueError(
+                        f"{nd.name}: {nd.c_in} -> {nd.c_out} channels; a "
+                        f"block keeps a width that is a multiple of 32 "
+                        f"or doubles it")
+                if nd.stride not in (1, 2) or (
+                        nd.stride == 2 and (h % 2 or w % 2)):
+                    raise ValueError(
+                        f"{nd.name}: stride {nd.stride} on a {h}x{w} map; "
+                        f"the 2x2 average-pooled shortcut needs stride 1, "
+                        f"or 2 on an even map")
+                h, w, c = nd.h_out, nd.w_out, nd.c_out
+            elif isinstance(nd, PoolFC):
+                if domain != "residual":
+                    raise ValueError(f"{nd.name}: PoolFC reads the float "
+                                     f"residual stream, not {domain} "
+                                     f"input")
+                if nd.n_in != c:
+                    raise ValueError(f"{nd.name}: n_in={nd.n_in} but the "
+                                     f"stream is {c} wide")
+                if i != len(self.nodes) - 1:
+                    raise ValueError(f"{nd.name}: PoolFC must be the "
+                                     f"terminal node")
+                domain = "logits"
+            elif isinstance(nd, IntegerEntry):
                 if domain != "float":
                     raise ValueError(
                         f"{nd.name}: integer layer after a binary layer "
@@ -306,6 +424,9 @@ class BNNSpec:
                                      f"terminal node")
             else:
                 raise ValueError(f"unknown node {nd!r}")
+        if domain == "residual":
+            raise ValueError(f"{self.name}: the residual stream must be "
+                             f"left through a terminal PoolFC")
 
 
 # ------------------------------------------------------------------ #
@@ -415,6 +536,57 @@ def spec_to_workload(spec: BNNSpec) -> Workload:
                 nd.name, nd.c_in, nd.c_out, nd.w_in, nd.h_in,
                 nd.w_out, nd.h_out, nd.kh,
                 integer=isinstance(nd, IntegerEntry), parts=nd.parts))
+        elif isinstance(nd, ResidualBlock):
+            conv.append(ConvLayer(
+                f"{nd.name}.conv3x3", nd.c_in, nd.c_in, nd.w_in, nd.h_in,
+                nd.w_out, nd.h_out, 3, integer=False))
+            conv.append(ConvLayer(
+                f"{nd.name}.conv1x1", nd.c_in, nd.c_out, nd.w_out,
+                nd.h_out, nd.w_out, nd.h_out, 1, integer=False))
         elif isinstance(nd, BinaryDense):
             fc.append(FCLayer(nd.name, nd.n_in, nd.n_out))
+        elif isinstance(nd, PoolFC):
+            fc.append(FCLayer(nd.name, nd.n_in, nd.classes, integer=True))
     return Workload(spec.name, spec.dataset, tuple(conv), tuple(fc))
+
+
+# ------------------------------------------------------------------ #
+# residual networks                                                    #
+# ------------------------------------------------------------------ #
+# ReActNet-A (arXiv:2003.03488 §3-4; the authors' imagenet_training/
+# reactnet.py): MobileNetV1's widths, every conv binary; (c_out, stride)
+# of each of the 13 blocks after the 32-channel stem
+REACTNET_A_BLOCKS = ((64, 1), (128, 2), (128, 1), (256, 2), (256, 1),
+                     (512, 2), (512, 1), (512, 1), (512, 1), (512, 1),
+                     (512, 1), (1024, 2), (1024, 1))
+
+
+def residual_net(name: str, input_shape: Tuple[int, int, int],
+                 stem: int, blocks: Sequence[Tuple[int, int]],
+                 classes: int, dataset: str = "") -> BNNSpec:
+    """A residual BNN as a spec: a 3x3 stride-2 IntegerEntry stem of
+    ``stem`` channels (zero padding 1), the float stream it opens, one
+    ResidualBlock per ``(c_out, stride)`` of ``blocks``, and PoolFC to
+    ``classes`` logits.  Blocks are named ``block1`` ... and the stem
+    ``stem``."""
+    h, w, c = input_shape
+    ho, wo = (h - 1) // 2 + 1, (w - 1) // 2 + 1
+    nodes: list = [IntegerEntry("stem", 3, 3, c, stem, h, w, ho, wo,
+                                stride=2, pad=1),
+                   ResidualEntry("stem.bn", stem)]
+    h, w, c = ho, wo, stem
+    for i, (c_out, s) in enumerate(blocks, 1):
+        blk = ResidualBlock(f"block{i}", c, c_out, h, w, s)
+        nodes.append(blk)
+        h, w, c = blk.h_out, blk.w_out, c_out
+    nodes.append(PoolFC("fc", c, classes))
+    spec = BNNSpec(name, tuple(input_shape), tuple(nodes), dataset=dataset)
+    spec.validate()
+    return spec
+
+
+def reactnet_a_imagenet() -> BNNSpec:
+    """ReActNet-A on ImageNet at its published widths: 224x224x3, a
+    32-channel stem, 13 blocks (64 ... 1024), 1000 classes."""
+    return residual_net("ReActNet-A", (224, 224, 3), 32,
+                        REACTNET_A_BLOCKS, 1000, dataset="Imagenet")

@@ -17,6 +17,14 @@ spec and returns a tuple of :class:`PlanStep`:
       tuning-table key up front (kernels.autotune memoizes), and the
       keys are recorded on the steps.
 
+A ResidualBlock lowers to four steps: ``residual_conv`` (the 3x3
+conv, direct or im2col by the same VMEM estimate, int32 sums),
+``residual_epilogue`` (zero-border correction, BN, shortcut, RPReLU,
+the next sign packed), ``residual_conv`` (the 1x1 conv, always an
+im2col popcount GEMM: its patch matrix is the packed map itself), and
+``residual_epilogue`` again.  ``residual_entry`` opens the stream after
+the entry conv; ``pool_fc`` closes it.
+
 Every step carries a human-readable ``detail`` string: ``CompiledBNN.
 describe()`` is the paper's mapping algorithm made inspectable.
 
@@ -28,13 +36,16 @@ performance, never in bits.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence, Tuple
 
 from repro.graph.ir import (Binarize, BinaryConv, BinaryDense, BNNSpec,
-                            BNThreshold, IntegerEntry, Logits, MaxPool)
+                            BNThreshold, IntegerEntry, Logits, MaxPool,
+                            PoolFC, ResidualBlock, ResidualEntry)
 from repro.kernels.fused_mlp import stack_plan
 from repro.kernels.ops import plan_conv_launch, plan_dense_launch
+from repro.runtime import spans
 
 __all__ = ["PlanStep", "batches_tuning_keys", "build_plan",
            "plan_tuning_keys"]
@@ -45,7 +56,8 @@ class PlanStep:
     """One executable step + the lowering decision that produced it.
 
     kind: integer_conv | float_pool | binarize | binary_conv |
-          packed_pool | flatten | fused_stack | dense | logits
+          packed_pool | flatten | fused_stack | dense | logits |
+          residual_entry | residual_conv | residual_epilogue | pool_fc
     args: static operands for the executor (param indices, geometry,
           impl choices);  keys: autotune keys prefetched for the step.
     """
@@ -61,6 +73,59 @@ class PlanStep:
 
 def _fmt_mb(b: int) -> str:
     return f"{b / 1e6:.2f}MB"
+
+
+def _residual_conv_launch(a: dict, batch: int, backend: Optional[str],
+                          vmem_budget: Optional[int]) -> dict:
+    """plan_conv_launch of a ``residual_conv`` step's recorded geometry
+    (int32 sums out: no threshold, no pack)."""
+    return plan_conv_launch(a["h"], a["w"], a["c"], a["f"], a["k"],
+                            a["k"], stride=a["stride"], padding=a["pad"],
+                            backend=backend, pack_out=False,
+                            impl=a["impl"], vmem_budget=vmem_budget,
+                            nb=batch)
+
+
+def _lower_residual_block(nd: ResidualBlock, idx: int, last: bool,
+                          batch: int, backend: Optional[str],
+                          vmem_budget: Optional[int], conv_impl: str):
+    """The four steps of one ResidualBlock (module docstring)."""
+    c, f = nd.c_in, nd.c_out
+    conv3 = {"res_idx": idx, "weight": "w1", "h": nd.h_in, "w": nd.w_in,
+             "c": c, "f": c, "k": 3, "stride": nd.stride, "pad": 1,
+             "impl": conv_impl}
+    d3 = _residual_conv_launch(conv3, batch, backend, vmem_budget)
+    conv3["impl"] = d3["impl"]
+    why = "forced" if conv_impl != "auto" else (
+        "strided window" if nd.stride > 1 else
+        f"resident {_fmt_mb(d3['vmem_bytes'])} "
+        + ("> budget" if d3["impl"] == "im2col" else "fits"))
+    conv1 = {"res_idx": idx, "weight": "w2", "h": nd.h_out, "w": nd.w_out,
+             "c": c, "f": f, "k": 1, "stride": 1, "pad": 0,
+             "impl": "im2col"}
+    d1 = _residual_conv_launch(conv1, batch, backend, vmem_budget)
+    shortcut = "avgpool2x2(x)" if nd.stride == 2 else "x"
+    halves = f", {c} tiled twice" if f == 2 * c else ""
+    return [
+        PlanStep("residual_conv", f"{nd.name}.conv3x3", conv3,
+                 f"packed conv {c}->{c} k3 s{nd.stride} p1, "
+                 f"impl={d3['impl']} ({why}); int32 sums, -1 border",
+                 (d3["key"],)),
+        PlanStep("residual_epilogue", f"{nd.name}.epilogue1",
+                 {"res_idx": idx, "part": 1, "pool": nd.stride == 2,
+                  "border": (nd.h_in, nd.w_in, 3, nd.stride, 1),
+                  "next": True},
+                 f"zero-border correction, BN1, + {shortcut}, RPReLU1; "
+                 f"float stream + sign(u + rsign2) packed"),
+        PlanStep("residual_conv", f"{nd.name}.conv1x1", conv1,
+                 f"packed conv {c}->{f} k1 as an im2col popcount GEMM; "
+                 f"int32 sums", (d1["key"],)),
+        PlanStep("residual_epilogue", f"{nd.name}.epilogue2",
+                 {"res_idx": idx, "part": 2, "pool": False,
+                  "border": None, "next": not last},
+                 f"BN2, + u{halves}, RPReLU2; float stream"
+                 + ("" if last else " + the next block's sign packed")),
+    ]
 
 
 def _segment_dense_run(run, k0: int, batch: int,
@@ -151,7 +216,10 @@ def plan_tuning_keys(spec: BNNSpec, plan: Tuple[PlanStep, ...],
     conv_nodes = spec.conv_nodes
     keys = []
     for s in plan:
-        if s.kind == "binary_conv":
+        if s.kind == "residual_conv":
+            keys.append(_residual_conv_launch(
+                s.args, batch, backend, vmem_budget)["key"])
+        elif s.kind == "binary_conv":
             nd = conv_nodes[s.args["conv_idx"]]
             d = plan_conv_launch(
                 nd.h_in, nd.w_in, nd.c_in, nd.c_out, nd.kh, nd.kw,
@@ -209,10 +277,35 @@ def build_plan(spec: BNNSpec, backend: Optional[str] = None,
     domain = "float" if len(spec.input_shape) == 3 else "packed_flat"
     h, w = (spec.input_shape[:2] if domain == "float" else (0, 0))
     nodes = spec.nodes
+    n_blocks = len(spec.residual_blocks)
+    res_i = 0
     i = 0
     while i < len(nodes):
         nd = nodes[i]
-        if isinstance(nd, IntegerEntry):
+        if isinstance(nd, ResidualEntry):
+            steps.append(PlanStep(
+                "residual_entry", nd.name,
+                {"conv_idx": conv_i - 1},
+                f"+ BN bias: opens the float32 residual stream "
+                f"[{h}x{w}x{nd.channels}]; sign(x + block1.rsign1) "
+                f"packed"))
+        elif isinstance(nd, ResidualBlock):
+            t0 = time.perf_counter() if spans.on else 0.0
+            steps.extend(_lower_residual_block(
+                nd, res_i, res_i == n_blocks - 1, batch, backend,
+                vmem_budget, conv_impl))
+            res_i += 1
+            if t0:
+                spans.record("setup.residual", t0, time.perf_counter(),
+                             block=nd.name, impl=steps[-4].args["impl"])
+            h, w = nd.h_out, nd.w_out
+        elif isinstance(nd, PoolFC):
+            steps.append(PlanStep(
+                "pool_fc", nd.name, {},
+                f"global average pool {h}x{w} + float32 dense "
+                f"{nd.n_in}->{nd.classes} at highest precision (XLA) "
+                f"-> logits"))
+        elif isinstance(nd, IntegerEntry):
             steps.append(PlanStep(
                 "integer_conv", nd.name,
                 {"conv_idx": conv_i, "stride": nd.stride, "pad": nd.pad},

@@ -37,15 +37,18 @@ from repro.kernels.csa import col_block
 from repro.kernels.pack import pack as _pack_kernel
 from repro.kernels.packed import (PackedArray, adopt_packed,
                                   default_backend, get_backend, round_up)
-from repro.kernels.packed_conv import (conv_vmem_bytes, im2col_words,
+from repro.kernels.packed_conv import (conv_int32_vmem_bytes,
+                                       conv_vmem_bytes, im2col_words,
                                        out_size, packed_conv2d,
                                        pad_words_spatial)
 from repro.kernels.popcount_gemm import popcount_gemm as _pop_kernel
+from repro.kernels.residual import residual_epilogue as _res_kernel
+from repro.kernels.residual import residual_epilogue_ref
 from repro.kernels.xnor_gemm import xnor_gemm as _xnor_kernel
 
 __all__ = ["binarize_pack", "binary_binary_dense", "binary_conv2d",
            "binary_dense", "conv_padding", "default_backend", "mask_rows",
-           "plan_conv_launch", "plan_dense_launch"]
+           "plan_conv_launch", "plan_dense_launch", "residual_epilogue"]
 
 Packable = Union[PackedArray, jax.Array]
 Threshold = Union[int, float, jax.Array]
@@ -339,11 +342,15 @@ def plan_conv_launch(h: int, w: int, c: int, f: int, kh: int, kw: int,
         bf_run = col_block(fp, blocks.bn, pack_out)
         budget = (_pconv.VMEM_BUDGET_BYTES if vmem_budget is None
                   else vmem_budget)
-        vmem = conv_vmem_bytes(h + 2 * pad_h, w + 2 * pad_w, c32, kh, kw,
-                               ho * wo, bf_run)
+        vmem = (conv_vmem_bytes(h + 2 * pad_h, w + 2 * pad_w, c32, kh, kw,
+                                ho * wo, bf_run) if pack_out else
+                conv_int32_vmem_bytes(h + 2 * pad_h, w + 2 * pad_w, c32,
+                                      kh, kw, ho, wo, bf_run))
         if impl == "auto":
-            # image/planes can't sit resident -> im2col
-            impl = "im2col" if vmem > budget else "direct"
+            # image/planes can't sit resident -> im2col; so too a
+            # strided window, which the direct kernel's re-slice of the
+            # resident image cannot lower to on the TPU (a 3-D gather)
+            impl = "im2col" if vmem > budget or stride > 1 else "direct"
         d.update(impl=impl, op=op, blocks=blocks, vmem_bytes=vmem,
                  vmem_budget=budget,
                  key=(op, kb.name, ho * wo, fp, kh * kw * c32))
@@ -394,9 +401,10 @@ def binary_conv2d(xp: PackedArray, wf: PackedArray, stride: int = 1,
     impl: "direct" (im2col-free sliding window, one VMEM-resident image
     per grid step), "im2col" (word-granularity patch matrix through
     popcount_gemm), or "auto" (default: direct unless the estimated
-    resident footprint exceeds the VMEM budget, then im2col — the same
-    silent perf fallback fused_mlp uses).  The xla backend runs the
-    jnp sign-conv oracle; all paths are bit-identical
+    resident footprint exceeds the VMEM budget or the stride is above
+    1, then im2col — the same silent perf fallback fused_mlp uses).
+    The xla backend runs the jnp sign-conv oracle; all paths are
+    bit-identical
     (tests/test_conv.py).
     """
     if pack_out and threshold is None:
@@ -481,3 +489,20 @@ def binary_conv2d(xp: PackedArray, wf: PackedArray, stride: int = 1,
         return PackedArray(y[:, :, :nw].reshape(nb, ho, wo, nw),
                            length=f, axis=-1)
     return y[:, :, :f].reshape(nb, ho, wo, f)
+
+
+def residual_epilogue(s: jax.Array, sc: jax.Array, vec: jax.Array,
+                      table: Optional[jax.Array] = None,
+                      rows: Optional[Tuple[int, ...]] = None,
+                      pack: bool = True, backend: Optional[str] = None):
+    """The float epilogue of a residual binary conv
+    (kernels/residual.py): int32 sums [N, HO, WO, G*C] (-1 border) ->
+    ``(out, bits)``, the float32 stream and the channel-packed
+    ``sign(out + next)`` PackedArray (None without ``pack``).  Kernel
+    backends run the ``residual_epilogue`` Pallas kernel, "xla" its jnp
+    twin; both evaluate one expression, so they are bit-identical."""
+    be = get_backend(backend)
+    if not be.uses_kernels:
+        return residual_epilogue_ref(s, sc, vec, table, rows, pack=pack)
+    return _res_kernel(s, sc, vec, table, rows, pack=pack,
+                       interpret=be.interpret)

@@ -56,7 +56,8 @@ from repro.kernels.csa import (col_block, csa_finalize, csa_fold,
                                pack_bit_planes)
 from repro.kernels.packed import VMEM_BUDGET_BYTES
 
-__all__ = ["VMEM_BUDGET_BYTES", "conv_vmem_bytes", "im2col_words",
+__all__ = ["VMEM_BUDGET_BYTES", "conv_int32_vmem_bytes", "conv_vmem_bytes",
+           "im2col_words",
            "out_size", "packed_conv2d", "pad_words_spatial"]
 
 
@@ -76,6 +77,25 @@ def conv_vmem_bytes(h_pad: int, w_pad: int, c32: int, kh: int, kw: int,
     wblock = 4 * kh * kw * c32 * bf
     planes = 5 * 4 * m * bf
     return image + wblock + planes + 4 * m * bf
+
+
+def conv_int32_vmem_bytes(h_pad: int, w_pad: int, c32: int, kh: int,
+                          kw: int, ho: int, wo: int, bf: int) -> int:
+    """Per-grid-step resident footprint of the direct kernel's int32
+    output form (sums, or +-1 without pack_out) as the v5e compiler
+    lays it out: it keeps one [m, bf] XNOR plane live per (tap, word),
+    the whole unrolled fan-in rather than the 5 planes
+    ``conv_vmem_bytes`` counts, and where ``wo`` is not a multiple of
+    the 8-row sublane tile each plane's window relayout costs about
+    half as many rows again plus 288.  Fitted to the scoped-VMEM asks
+    of v5e compiles at 3x3, 7-56 rows and 1-32 words (3.6-178 MB),
+    every one of which it bounds from above (DESIGN.md §7)."""
+    m = ho * wo
+    rows = m if wo % 8 == 0 else (3 * m) // 2 + 288
+    image = 4 * h_pad * w_pad * c32
+    wblock = 4 * kh * kw * c32 * bf
+    planes = kh * kw * c32 * 4 * rows * bf
+    return image + wblock + planes + 2 * 4 * m * bf
 
 
 def _window(x, i_kh: int, i_kw: int, stride: int, ho: int, wo: int):

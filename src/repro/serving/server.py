@@ -284,8 +284,9 @@ class _Flight:
 class BNNServer:
     """Serving front door over a compiled BNN (see module docstring).
 
-    compiled: the CompiledBNN to serve; params: its bound parameter
-    tree (replicated onto ``mesh`` at construction); max_batch: bucket
+    compiled: the CompiledBNN to serve; params: its parameter tree
+    (bound by ``compiled.bind`` and replicated onto ``mesh`` at
+    construction); max_batch: bucket
     ceiling, rounded up to a power of two; mesh: a jax Mesh with a
     "data" axis for data-parallel dispatch, or None for single-device;
     donate: donate the per-dispatch input buffer to XLA (safe — the
@@ -345,7 +346,7 @@ class BNNServer:
         self.max_retries = max_retries
         self.retry_backoff_s = retry_backoff_s
         self.supervise_interval_s = supervise_interval_s
-        self.params = replicate(params, mesh)
+        self.params = replicate(compiled.bind(params), mesh)
         if donate:
             _filter_donation_warning()
         self._row_shape = tuple(compiled.spec.input_shape)

@@ -1,5 +1,6 @@
 """Compile every Pallas kernel of the BinaryNet serving path for a TPU
-v5e, at BinaryNet CIFAR-10 widths, without a chip.
+v5e, at BinaryNet CIFAR-10 widths, and ReActNet-A's residual epilogue
+at its widths, without a chip.
 
 The TPU compiler ships with jaxlib and compiles for a described (not
 attached) topology, so this refuses here what the chip's compiler would
@@ -24,11 +25,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import graph
 from repro.core.workloads import binarynet_cifar10
+from repro.kernels import ops
 from repro.kernels.fused_mlp import fused_binary_mlp
 from repro.kernels.pack import pack
 from repro.kernels.packed import PackedArray
 from repro.kernels.packed_conv import packed_conv2d
+from repro.graph.ir import residual_net
 from repro.kernels.popcount_gemm import popcount_gemm
+from repro.kernels.residual import border_classes, residual_epilogue
 from repro.kernels.xnor_gemm import xnor_gemm
 
 U32, I32, F32 = jnp.uint32, jnp.int32, jnp.float32
@@ -86,6 +90,36 @@ def _fc1_fc2(x, w1, w2, t1, t2):
         [t1, t2], backend="pallas").words
 
 
+def _epilogue(hw, c, groups, stride, border, pack):
+    """A ReActNet-A block epilogue over 8 images: int32 sums of
+    groups * c channels on the hw x hw output map, the c-wide float32
+    shortcut, the [6, groups * c] rows and, after a 3x3 conv of stride
+    ``stride``, the zero-border table."""
+    rows = None
+    n_rows = 1
+    if border:
+        rows, _, rsets, _ = border_classes(hw * stride, hw * stride, 3,
+                                           stride, 1)
+        n_rows = len(rsets)
+
+    def fn(s, sc, vec, table):
+        return residual_epilogue(s, sc, vec, table if border else None,
+                                 rows, pack=pack)[0]
+    cp = groups * c
+    return fn, ((8, hw, hw, cp), I32), ((8, hw, hw, c), F32), \
+        ((6, cp), F32), ((n_rows, hw, cp), I32)
+
+
+def _sums_conv(hw, c):
+    """A 3x3 stride-1 conv of int32 sums (ReActNet-A's form) for 8
+    images, through binary_conv2d's default impl="auto" dispatch."""
+    def fn(x, w):
+        return ops.binary_conv2d(PackedArray(x, c, -1),
+                                 PackedArray(w, c, 2), stride=1, padding=1,
+                                 backend="pallas")
+    return fn, ((8, hw, hw, c // 32), U32), ((3, 3, c // 32, c), U32)
+
+
 # name -> (function, operand (shape, dtype)s), at the padded shapes
 # ops.py dispatch hands each kernel for a batch of 8 BinaryNet images
 KERNELS = {
@@ -106,6 +140,20 @@ KERNELS = {
     "xnor_gemm": (lambda x, w, a: xnor_gemm(x, w, a),
                   ((128, 1024), jnp.bfloat16), ((32, 1024), U32),
                   ((1024,), F32)),
+    # ReActNet-A: block1's epilogues (32 channels, 112x112; the second
+    # adds the stream to both halves of 64), block2's after a stride-2
+    # conv, block13's last (no signs packed)
+    "residual_epilogue block1.1": _epilogue(112, 32, 1, 1, True, True),
+    "residual_epilogue block1.2": _epilogue(112, 32, 2, 1, False, True),
+    "residual_epilogue block2.1": _epilogue(56, 64, 1, 2, True, True),
+    "residual_epilogue block13.2": _epilogue(7, 1024, 1, 1, False, False),
+    # ReActNet-A's stride-1 3x3 convs, int32 sums, under the default
+    # dispatch: the int32 estimate sends block3 and block13 to im2col
+    # (their direct kernels ask 48 MB of VMEM), and keeps a map it
+    # bounds below the budget direct
+    "popcount_gemm block3.conv3x3": _sums_conv(56, 128),
+    "popcount_gemm block13.conv3x3": _sums_conv(7, 1024),
+    "packed_conv2d 8x8x1024 sums": _sums_conv(8, 1024),
     "xnor_gemm pack_out": (
         lambda x, w, a, t: xnor_gemm(x, w, a, threshold_vec=t,
                                      pack_out=True),
@@ -131,3 +179,19 @@ def test_binarynet_forward_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((8, 32, 32, 3), F32, sharding=one_chip)
     hlo = jax.jit(cb.apply).lower(params, x).compile().as_text()
     assert _kernels(hlo) == cb.launch_count() == 8
+
+
+def test_residual_forward_compiles_for_v5e(one_chip):
+    """A small residual spec's whole forward at batch 8 on the kernels
+    its plan picks (direct 3x3 convs, im2col at stride 2 and for the
+    1x1s, the epilogues): every planned launch is a Pallas kernel."""
+    spec = residual_net("tiny-reactnet", (32, 32, 3), 32,
+                        ((64, 1), (128, 2), (128, 1)), 10)
+    cb = graph.compile(spec, backend="pallas", batch=8)
+    params = jax.eval_shape(cb.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        params)
+    x = jax.ShapeDtypeStruct((8, 32, 32, 3), F32, sharding=one_chip)
+    hlo = jax.jit(cb.apply).lower(params, x).compile().as_text()
+    assert _kernels(hlo) == cb.launch_count() == 13
