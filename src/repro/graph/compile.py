@@ -51,7 +51,7 @@ from repro.graph.ir import (BinaryConv, BinaryDense, BNNSpec,
                             from_dense_stack, from_workload,
                             spec_to_workload)
 from repro.graph.passes import (PlanStep, batches_tuning_keys, build_plan,
-                                plan_tuning_keys)
+                                mxu_convs, plan_tuning_keys)
 from repro.kernels import ops as kops
 from repro.kernels.fused_mlp import fused_binary_mlp
 from repro.kernels.packed import PackedArray
@@ -148,23 +148,30 @@ class CompiledBNN:
                 f"(legacy chain: {self.legacy_launch_count()})")
         return "\n".join([head] + [f"  {s}" for s in self.plan])
 
-    # plan steps that are one kernel launch each
+    # plan steps that are one kernel launch each (a residual_conv on
+    # the MXU is an XLA conv, not a launch)
     _LAUNCHES = ("binarize", "binary_conv", "dense", "residual_entry",
                  "residual_conv", "residual_epilogue")
 
+    @classmethod
+    def _launches(cls, s: PlanStep) -> int:
+        if s.kind == "fused_stack":
+            return 1
+        return int(s.kind in cls._LAUNCHES
+                   and s.args.get("impl") != "mxu")
+
     def launch_count(self) -> int:
         """Kernel launches per forward pass under this plan (the
-        integer-entry XLA convs, the float head and reshapes don't
-        count; a residual block is four: two convs, two epilogues)."""
-        return sum(s.kind in self._LAUNCHES + ("fused_stack",)
-                   for s in self.plan)
+        integer-entry and MXU XLA convs, the float head and reshapes
+        don't count; a residual block is two epilogues, plus its two
+        convs where they are forced off the MXU)."""
+        return sum(self._launches(s) for s in self.plan)
 
     def legacy_launch_count(self) -> int:
         """What the legacy layer-by-layer builder chain would launch:
         every fused_stack segment unrolls to one launch per layer."""
         return sum(len(s.args["fc_indices"]) if s.kind == "fused_stack"
-                   else s.kind in self._LAUNCHES
-                   for s in self.plan)
+                   else self._launches(s) for s in self.plan)
 
     def tuning_keys_for_batch(self, batch: int) -> Tuple[tuple, ...]:
         """The autotune keys this plan's launches resolve to at a
@@ -609,7 +616,8 @@ def compile(spec: Union[BNNSpec, Workload],
     kernels.packed.VMEM_BUDGET_BYTES); batch: row hint the plan is
     computed for (decisions that depend on it are re-checked at trace
     time and are bit-identical either way); conv_impl: force
-    "direct"/"im2col" instead of the "auto" VMEM estimate.
+    "direct"/"im2col" instead of "auto" (int32-sum convs on the MXU,
+    thresholded ones by the VMEM estimate).
     """
     t0 = time.perf_counter() if spans.on else 0.0
     if isinstance(spec, Workload):
@@ -619,7 +627,7 @@ def compile(spec: Union[BNNSpec, Workload],
                       batch=batch, conv_impl=conv_impl)
     if t0:
         spans.record("setup.compile", t0, time.perf_counter(),
-                     steps=len(plan))
+                     steps=len(plan), mxu_convs=mxu_convs(plan))
     return CompiledBNN(spec, plan, backend, vmem_budget, batch)
 
 

@@ -11,19 +11,22 @@ spec and returns a tuple of :class:`PlanStep`:
       are greedily packed into fused_mlp megakernel launches under the
       VMEM budget (kernels.fused_mlp.stack_plan, THE shared
       residency rule), falling back to chained per-layer launches;
-  (4) conv impl selection — direct vs im2col via the VMEM-residency
-      estimate (kernels.ops.plan_conv_launch, shared with dispatch);
+  (4) conv impl selection — int32 sums on the MXU, thresholded convs
+      direct vs im2col via the VMEM-residency estimate
+      (kernels.ops.plan_conv_launch, shared with dispatch);
   (5) autotune prefetch — every planned kernel launch resolves its
       tuning-table key up front (kernels.autotune memoizes), and the
       keys are recorded on the steps.
 
 A ResidualBlock lowers to four steps: ``residual_conv`` (the 3x3
-conv, direct or im2col by the same VMEM estimate, int32 sums),
+conv, int32 sums: an XLA int8 conv of the unpacked +-1 values on the
+MXU under ``auto``, direct or im2col when forced),
 ``residual_epilogue`` (zero-border correction, BN, shortcut, RPReLU,
-the next sign packed), ``residual_conv`` (the 1x1 conv, always an
-im2col popcount GEMM: its patch matrix is the packed map itself), and
-``residual_epilogue`` again.  ``residual_entry`` opens the stream after
-the entry conv; ``pool_fc`` closes it.
+the next sign packed), ``residual_conv`` (the 1x1 conv, likewise on
+the MXU; forced, an im2col popcount GEMM, whose patch matrix is the
+packed map itself), and ``residual_epilogue`` again.
+``residual_entry`` opens the stream after the entry conv; ``pool_fc``
+closes it.
 
 Every step carries a human-readable ``detail`` string: ``CompiledBNN.
 describe()`` is the paper's mapping algorithm made inspectable.
@@ -47,7 +50,7 @@ from repro.kernels.fused_mlp import stack_plan
 from repro.kernels.ops import plan_conv_launch, plan_dense_launch
 from repro.runtime import spans
 
-__all__ = ["PlanStep", "batches_tuning_keys", "build_plan",
+__all__ = ["PlanStep", "batches_tuning_keys", "build_plan", "mxu_convs",
            "plan_tuning_keys"]
 
 
@@ -75,6 +78,17 @@ def _fmt_mb(b: int) -> str:
     return f"{b / 1e6:.2f}MB"
 
 
+def mxu_convs(plan: Sequence[PlanStep]) -> int:
+    """Conv steps of a plan that run as XLA convs on the MXU."""
+    return sum(s.kind == "residual_conv" and s.args["impl"] == "mxu"
+               for s in plan)
+
+
+def _keys(d: dict) -> Tuple[tuple, ...]:
+    """The tuning key of a planned launch; none for an MXU conv."""
+    return (d["key"],) if "key" in d else ()
+
+
 def _residual_conv_launch(a: dict, batch: int, backend: Optional[str],
                           vmem_budget: Optional[int]) -> dict:
     """plan_conv_launch of a ``residual_conv`` step's recorded geometry
@@ -96,21 +110,23 @@ def _lower_residual_block(nd: ResidualBlock, idx: int, last: bool,
              "impl": conv_impl}
     d3 = _residual_conv_launch(conv3, batch, backend, vmem_budget)
     conv3["impl"] = d3["impl"]
-    why = "forced" if conv_impl != "auto" else (
-        "strided window" if nd.stride > 1 else
-        f"resident {_fmt_mb(d3['vmem_bytes'])} "
-        + ("> budget" if d3["impl"] == "im2col" else "fits"))
     conv1 = {"res_idx": idx, "weight": "w2", "h": nd.h_out, "w": nd.w_out,
              "c": c, "f": f, "k": 1, "stride": 1, "pad": 0,
-             "impl": "im2col"}
+             "impl": "auto" if conv_impl == "auto" else "im2col"}
     d1 = _residual_conv_launch(conv1, batch, backend, vmem_budget)
+    conv1["impl"] = d1["impl"]
+    if d3["impl"] == "mxu":
+        how3 = "impl=mxu (int32 sums: XLA int8 conv), -1 border"
+        how1 = ", impl=mxu (int32 sums: XLA int8 conv)"
+    else:
+        how3 = f"impl={d3['impl']} (forced); int32 sums, -1 border"
+        how1 = " as an im2col popcount GEMM; int32 sums"
     shortcut = "avgpool2x2(x)" if nd.stride == 2 else "x"
     halves = f", {c} tiled twice" if f == 2 * c else ""
     return [
         PlanStep("residual_conv", f"{nd.name}.conv3x3", conv3,
-                 f"packed conv {c}->{c} k3 s{nd.stride} p1, "
-                 f"impl={d3['impl']} ({why}); int32 sums, -1 border",
-                 (d3["key"],)),
+                 f"packed conv {c}->{c} k3 s{nd.stride} p1, {how3}",
+                 _keys(d3)),
         PlanStep("residual_epilogue", f"{nd.name}.epilogue1",
                  {"res_idx": idx, "part": 1, "pool": nd.stride == 2,
                   "border": (nd.h_in, nd.w_in, 3, nd.stride, 1),
@@ -118,8 +134,7 @@ def _lower_residual_block(nd: ResidualBlock, idx: int, last: bool,
                  f"zero-border correction, BN1, + {shortcut}, RPReLU1; "
                  f"float stream + sign(u + rsign2) packed"),
         PlanStep("residual_conv", f"{nd.name}.conv1x1", conv1,
-                 f"packed conv {c}->{f} k1 as an im2col popcount GEMM; "
-                 f"int32 sums", (d1["key"],)),
+                 f"packed conv {c}->{f} k1{how1}", _keys(d1)),
         PlanStep("residual_epilogue", f"{nd.name}.epilogue2",
                  {"res_idx": idx, "part": 2, "pool": False,
                   "border": None, "next": not last},
@@ -217,8 +232,8 @@ def plan_tuning_keys(spec: BNNSpec, plan: Tuple[PlanStep, ...],
     keys = []
     for s in plan:
         if s.kind == "residual_conv":
-            keys.append(_residual_conv_launch(
-                s.args, batch, backend, vmem_budget)["key"])
+            keys.extend(_keys(_residual_conv_launch(
+                s.args, batch, backend, vmem_budget)))
         elif s.kind == "binary_conv":
             nd = conv_nodes[s.args["conv_idx"]]
             d = plan_conv_launch(
@@ -297,7 +312,8 @@ def build_plan(spec: BNNSpec, backend: Optional[str] = None,
             res_i += 1
             if t0:
                 spans.record("setup.residual", t0, time.perf_counter(),
-                             block=nd.name, impl=steps[-4].args["impl"])
+                             block=nd.name, impl3=steps[-4].args["impl"],
+                             impl1=steps[-2].args["impl"])
             h, w = nd.h_out, nd.w_out
         elif isinstance(nd, PoolFC):
             steps.append(PlanStep(
@@ -336,7 +352,7 @@ def build_plan(spec: BNNSpec, backend: Optional[str] = None,
                 f"packed conv {nd.c_in}->{nd.c_out} k{nd.kh} "
                 f"s{nd.stride} p{nd.pad}, impl={d['impl']} ({why}); "
                 f"{thr.name} folded into the threshold->pack epilogue",
-                (d["key"],) if "key" in d else ()))
+                _keys(d)))
             conv_i += 1
             h, w = nd.h_out, nd.w_out
             i += 1                     # consume the fused BNThreshold

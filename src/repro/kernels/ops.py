@@ -312,13 +312,16 @@ def plan_conv_launch(h: int, w: int, c: int, f: int, kh: int, kw: int,
                      backend: Optional[str] = None, pack_out: bool = False,
                      impl: str = "auto", c32: Optional[int] = None,
                      vmem_budget: Optional[int] = None,
-                     nb: int = 1) -> dict:
+                     nb: int = 1, thresholded: bool = False) -> dict:
     """Static twin of the binary_conv2d dispatch decisions: output
-    geometry, the direct-vs-im2col choice via the VMEM-residency
-    estimate, and the tuning key of the launch that actually runs.
-    binary_conv2d routes its own ``impl="auto"`` decision through here,
-    so the compiled plan (graph/passes.py) can never drift from what
-    dispatch actually does.  A direct launch keys under
+    geometry, the impl, and the tuning key of the launch that actually
+    runs.  binary_conv2d routes its own ``impl="auto"`` decision
+    through here, so the compiled plan (graph/passes.py) can never
+    drift from what dispatch actually does.  A conv of int32 sums
+    (neither ``thresholded`` nor ``pack_out``) resolves to ``mxu``, an
+    XLA convolution with no Pallas launch, hence no tuning key and no
+    VMEM estimate.  A thresholded conv takes direct or im2col by the
+    VMEM-residency estimate: a direct launch keys under
     ``packed_conv[+pack]``; an im2col launch (explicit or
     auto-resolved) re-keys under ``popcount_gemm[+pack]`` with the
     flattened patch-matrix shape (M = nb*HO*WO rows — pass ``nb`` for
@@ -332,8 +335,16 @@ def plan_conv_launch(h: int, w: int, c: int, f: int, kh: int, kw: int,
     if c32 is None:
         c32 = (c + 31) // 32
     fp = kb.pad_n(f)
+    sums = not (thresholded or pack_out)
+    if impl == "auto" and sums:
+        impl = "mxu"
     d = {"ho": ho, "wo": wo, "pad_h": pad_h, "pad_w": pad_w,
          "c32": c32, "fp": fp, "backend": kb.name, "impl": impl}
+    if impl == "mxu":
+        if not sums:
+            raise ValueError("impl='mxu' returns int32 sums: it takes "
+                             "no threshold and no pack_out")
+        return d
     if impl != "im2col":
         op = "packed_conv+pack" if pack_out else "packed_conv"
         blocks = best_conv_blocks(op, ho, wo, fp, kh * kw * c32, kb.name)
@@ -400,18 +411,22 @@ def binary_conv2d(xp: PackedArray, wf: PackedArray, stride: int = 1,
 
     impl: "direct" (im2col-free sliding window, one VMEM-resident image
     per grid step), "im2col" (word-granularity patch matrix through
-    popcount_gemm), or "auto" (default: direct unless the estimated
-    resident footprint exceeds the VMEM budget or the stride is above
-    1, then im2col — the same silent perf fallback fused_mlp uses).
-    The xla backend runs the jnp sign-conv oracle; all paths are
-    bit-identical
+    popcount_gemm), "mxu" (int32 sums only: an XLA int8 convolution of
+    the unpacked +-1 values), or "auto" (default: mxu for int32 sums;
+    with a threshold, direct unless the estimated resident footprint
+    exceeds the VMEM budget or the stride is above 1, then im2col —
+    the same silent perf fallback fused_mlp uses).  The xla backend
+    runs the jnp sign-conv oracle; all paths are bit-identical
     (tests/test_conv.py).
     """
     if pack_out and threshold is None:
         raise ValueError("pack_out requires a threshold (binary output)")
-    if impl not in ("auto", "direct", "im2col"):
-        raise ValueError(f"impl must be 'auto', 'direct', or 'im2col', "
-                         f"got {impl!r}")
+    if impl not in ("auto", "direct", "im2col", "mxu"):
+        raise ValueError(f"impl must be 'auto', 'direct', 'im2col', or "
+                         f"'mxu', got {impl!r}")
+    if impl == "mxu" and threshold is not None:
+        raise ValueError("impl='mxu' returns int32 sums: it takes no "
+                         "threshold and no pack_out")
     if not isinstance(xp, PackedArray) or not isinstance(wf, PackedArray):
         raise ValueError("binary_conv2d takes PackedArray operands "
                          "(PackedArray.pack acts on axis -1, filters on "
@@ -452,6 +467,14 @@ def binary_conv2d(xp: PackedArray, wf: PackedArray, stride: int = 1,
 
     # align the word counts (odd C: both sides pad to the same C32)
     c32 = max(xp.n_words, wf.n_words)
+    # impl + tuning key through the shared static planner (the graph
+    # compiler records the same decision in its plan)
+    d = plan_conv_launch(h, w, c, f, kh, kw, stride=stride,
+                         padding=padding, backend=be.name,
+                         pack_out=pack_out, impl=impl, c32=c32,
+                         thresholded=threshold is not None)
+    if d["impl"] == "mxu":
+        return _mxu_conv2d(xp, wf, stride, pad_h, pad_w)
     xp = xp.pad_to(32 * c32)
     wf = wf.pad_to(32 * c32)
     xw = pad_words_spatial(xp.words, pad_h, pad_w)
@@ -459,14 +482,8 @@ def binary_conv2d(xp: PackedArray, wf: PackedArray, stride: int = 1,
     fp = be.pad_n(f)
     ww = _pad_dim(ww, fp, 1)
     thr, tvec = _split_threshold(threshold, f, fp)
-    # direct-vs-im2col + tuning key through the shared static planner
-    # (the graph compiler records the same decision in its plan)
-    d = plan_conv_launch(h, w, c, f, kh, kw, stride=stride,
-                         padding=padding, backend=be.name,
-                         pack_out=pack_out, impl=impl, c32=c32)
-    use_im2col = d["impl"] == "im2col"
 
-    if use_im2col:
+    if d["impl"] == "im2col":
         patches = im2col_words(xw, kh, kw, stride, ho, wo)
         # length counts the valid bits; the per-tap pad bits sit mid-row
         # but the GEMM closed form only counts them (packed_conv.py) —
@@ -489,6 +506,28 @@ def binary_conv2d(xp: PackedArray, wf: PackedArray, stride: int = 1,
         return PackedArray(y[:, :, :nw].reshape(nb, ho, wo, nw),
                            length=f, axis=-1)
     return y[:, :, :f].reshape(nb, ho, wo, f)
+
+
+def _mxu_conv2d(xp: PackedArray, wf: PackedArray, stride: int,
+                pad_h: int, pad_w: int) -> jax.Array:
+    """int32 sums of the +-1 conv on the MXU: the packed map, padded
+    with zero words (a -1 border, as every impl has it), and the packed
+    filters unpack to int8 +-1 inside the jit, and one XLA convolution
+    sums them in int32.  Exact: a product is +-1 and |sum| <= KH*KW*C
+    (9216 in ReActNet-A), far inside int32.  A 1x1 stride-1 conv is a
+    [N*H*W, C] x [C, F] dot, whose row-major int32 output the epilogue
+    reads with no relayout copy (faster on v5e, PERF.md)."""
+    w = wf.unpack(jnp.int8)
+    if w.shape[:2] == (1, 1) and stride == 1 and pad_h == pad_w == 0:
+        x = xp.unpack(jnp.int8)
+        y = jnp.dot(x.reshape(-1, x.shape[-1]), w[0, 0],
+                    preferred_element_type=jnp.int32)
+        return y.reshape(*x.shape[:-1], w.shape[-1])
+    x = xp.with_words(pad_words_spatial(xp.words, pad_h, pad_w))
+    return jax.lax.conv_general_dilated(
+        x.unpack(jnp.int8), w, (stride, stride),
+        "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.int32)
 
 
 def residual_epilogue(s: jax.Array, sc: jax.Array, vec: jax.Array,

@@ -1,8 +1,9 @@
 """Packed binary conv2d datapath (ISSUE 3).
 
-Pins (1) bit-exactness of the direct (im2col-free) Pallas conv and the
-word-level im2col fallback against the jnp sign-conv oracle across
-backends, over odd C/F, stride-2 and valid-padding edge cases; (2) the
+Pins (1) bit-exactness of the direct (im2col-free) Pallas conv, the
+word-level im2col fallback and the int8 MXU conv of int32 sums against
+the jnp sign-conv oracle across backends, over odd C/F, stride-2, 1x1
+and valid-padding edge cases; (2) the
 fused threshold->pack conv path materializing no int32 NHWC
 intermediate (jaxpr regression); (3) OR-max-pooling on packed words;
 (4) the conv folded-BN -> per-channel-threshold rewrite; (5) geometry
@@ -13,14 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis.jaxpr_audit import eqn_shapes
+from repro.analysis.jaxpr_audit import eqn_shapes, iter_eqns
 from repro.core.bnn_layers import (binary_conv, binary_weight_conv,
                                    fold_bn_threshold,
                                    fold_conv_to_channel_thresholds,
                                    maxpool_packed)
 from repro.core.workloads import alexnet_imagenet, binarynet_cifar10
 from repro.kernels import ref
-from repro.kernels.ops import binary_conv2d
+from repro.kernels.ops import binary_conv2d, plan_conv_launch
 from repro.kernels.packed import PackedArray, pack_words
 from repro.models.layers import (infer_conv_geometry, infer_pool,
                                  packed_cnn_apply, packed_cnn_init,
@@ -46,8 +47,10 @@ def _pack_io(rng, nb, h, w, c, f, k):
     (1, 9, 9, 64, 32, 3, 2, "same"),     # stride 2
     (1, 7, 7, 16, 10, 5, 1, "valid"),    # valid padding, k=5
     (2, 6, 6, 3, 40, 3, 1, "same"),      # C < 32 (single partial word)
+    (2, 8, 8, 32, 32, 3, 1, "same"),     # one whole word
+    (2, 6, 6, 64, 128, 1, 1, "same"),    # 1x1, ReActNet's doubling
 ])
-@pytest.mark.parametrize("impl", ["direct", "im2col"])
+@pytest.mark.parametrize("impl", ["direct", "im2col", "mxu"])
 def test_conv_bit_exact_vs_oracle(nb, h, w, c, f, k, s, pad, impl):
     rng = np.random.default_rng(nb * 11 + c * 3 + f + k + s)
     x, wts, xp, wf = _pack_io(rng, nb, h, w, c, f, k)
@@ -111,19 +114,21 @@ def test_conv_non_square_kernel_same_pad():
 
 
 def test_conv_auto_falls_back_to_im2col(monkeypatch):
-    """impl="auto" must route to the im2col path when the direct
-    kernel's estimated footprint exceeds the VMEM budget — and stay
-    bit-identical."""
+    """impl="auto" must route a thresholded conv to the im2col path
+    when the direct kernel's estimated footprint exceeds the VMEM
+    budget — and stay bit-identical."""
     from repro.kernels import packed_conv
 
     rng = np.random.default_rng(31)
     _, _, xp, wf = _pack_io(rng, 1, 6, 6, 32, 32, 3)
-    want = binary_conv2d(xp, wf, backend="xla")
-    auto = binary_conv2d(xp, wf, backend="interpret", impl="auto")
+    want = binary_conv2d(xp, wf, threshold=0, backend="xla")
+    auto = binary_conv2d(xp, wf, threshold=0, backend="interpret",
+                         impl="auto")
     np.testing.assert_array_equal(np.asarray(auto), np.asarray(want))
 
     monkeypatch.setattr(packed_conv, "VMEM_BUDGET_BYTES", 0)
-    fell_back = binary_conv2d(xp, wf, backend="interpret", impl="auto")
+    fell_back = binary_conv2d(xp, wf, threshold=0, backend="interpret",
+                              impl="auto")
     np.testing.assert_array_equal(np.asarray(fell_back), np.asarray(want))
     # routing check: with budget 0 the jaxpr contains the im2col patch
     # matrix; with the real budget it does not
@@ -131,10 +136,50 @@ def test_conv_auto_falls_back_to_im2col(monkeypatch):
     def shapes(fn):
         return eqn_shapes(fn, xp, wf, dtype=jnp.uint32)
     assert (m, k32) in shapes(
-        lambda a, b: binary_conv2d(a, b, backend="interpret", impl="auto"))
+        lambda a, b: binary_conv2d(a, b, threshold=0, backend="interpret",
+                                   impl="auto"))
     monkeypatch.undo()
     assert (m, k32) not in shapes(
-        lambda a, b: binary_conv2d(a, b, backend="interpret", impl="auto"))
+        lambda a, b: binary_conv2d(a, b, threshold=0, backend="interpret",
+                                   impl="auto"))
+
+
+def _eqns(fn, *args):
+    return list(iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("k,prim", [(3, "conv_general_dilated"),
+                                    (1, "dot_general")])
+def test_auto_int32_sums_take_the_mxu(k, prim):
+    """With no threshold, impl="auto" runs one XLA convolution (a dot
+    for a 1x1) of int8 +-1 values summing in int32, and no Pallas
+    kernel; a thresholded conv keeps its Pallas launch."""
+    rng = np.random.default_rng(12)
+    _, _, xp, wf = _pack_io(rng, 2, 6, 6, 40, 24, k)
+    eqns = _eqns(lambda a, b: binary_conv2d(a, b, backend="interpret"),
+                 xp, wf)
+    (conv,) = [e for e in eqns if e.primitive.name == prim]
+    assert [v.aval.dtype for v in conv.invars] == [jnp.int8, jnp.int8]
+    assert conv.outvars[0].aval.dtype == jnp.int32
+    assert not [e for e in eqns if e.primitive.name == "pallas_call"]
+    thr = _eqns(lambda a, b: binary_conv2d(a, b, threshold=0,
+                                           backend="interpret"), xp, wf)
+    assert [e for e in thr if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("pack_out", [False, True])
+@pytest.mark.parametrize("backend", ["interpret", "xla"])
+def test_mxu_conv_refuses_a_threshold(backend, pack_out):
+    """impl="mxu" gives int32 sums: a threshold, with or without the
+    packed output, is refused, in dispatch and in the planner."""
+    rng = np.random.default_rng(13)
+    _, _, xp, wf = _pack_io(rng, 1, 5, 5, 32, 32, 3)
+    with pytest.raises(ValueError, match="mxu"):
+        binary_conv2d(xp, wf, threshold=0, pack_out=pack_out,
+                      backend=backend, impl="mxu")
+    with pytest.raises(ValueError, match="mxu"):
+        plan_conv_launch(5, 5, 32, 32, 3, 3, backend=backend,
+                         pack_out=pack_out, thresholded=True, impl="mxu")
 
 
 def test_conv_validates_operands():

@@ -8,8 +8,10 @@ stride-2 doubling block and a stride-1 block of one width.
   on seeded random weights: the float stream bit-identical, the logits
   within the benchmark configuration's row gap;
 * the zero-border correction exact for every border class at strides
-  1 and 2, even and odd maps, direct and im2col, against a conv of
-  zero-padded +-1 values;
+  1 and 2, even and odd maps, direct, im2col and mxu, against a conv
+  of zero-padded +-1 values;
+* the whole forward the same bits under the MXU plan and the pinned
+  im2col plan;
 * the ``residual_epilogue`` kernel bit-identical to its jnp twin;
 * the validator: residual specs accepted, a width mismatch, a residual
   block fed packed input and other misuse rejected;
@@ -130,6 +132,22 @@ def test_backends_bit_identical():
     np.testing.assert_array_equal(outs[0], outs[1])
 
 
+def test_mxu_plan_matches_im2col_plan():
+    """The auto plan's int8 MXU convs and the pinned im2col plan's
+    popcount GEMMs give the same logits, bit for bit."""
+    spec = _tiny()
+    x = _images(4, seed=4)
+    auto = graph.compile(spec, backend="interpret", batch=4)
+    pinned = graph.compile(spec, backend="interpret", batch=4,
+                           conv_impl="im2col")
+    assert {s.args["impl"] for s in auto.plan
+            if s.kind == "residual_conv"} == {"mxu"}
+    params = auto.init(jax.random.PRNGKey(6))
+    got = np.asarray(jax.jit(auto.apply)(params, x))
+    want = np.asarray(jax.jit(pinned.apply)(params, x))
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
 def test_bind_prepares_border_tables():
     """``bind`` computes each block's zero-border table once from its
     packed w1; the forward reads that table (zeroing it changes the
@@ -157,7 +175,8 @@ def test_bind_prepares_border_tables():
 # ------------------------------------------------------------------ #
 @pytest.mark.parametrize("backend,impl", [("xla", "auto"),
                                           ("interpret", "direct"),
-                                          ("interpret", "im2col")])
+                                          ("interpret", "im2col"),
+                                          ("interpret", "mxu")])
 @pytest.mark.parametrize("stride", [1, 2])
 @pytest.mark.parametrize("h,w", [(6, 6), (7, 5), (1, 4), (2, 3)])
 def test_zero_border_correction(backend, impl, stride, h, w):
@@ -348,30 +367,39 @@ def test_reactnet_plan():
 
 def test_auto_conv_impl_takes_im2col_at_stride_2():
     """The direct kernel's window is a strided gather above stride 1,
-    which the TPU cannot lower: auto dispatch takes im2col there.  The
-    shape is one whose stride-1 direct form compiles for v5e."""
+    which the TPU cannot lower: auto dispatch of a thresholded conv
+    takes im2col there.  The shape is one whose stride-1 direct form
+    compiles for v5e."""
     d1 = kops.plan_conv_launch(16, 16, 128, 128, 3, 3, stride=1,
-                               padding=1, backend="pallas", nb=8)
+                               padding=1, backend="pallas", nb=8,
+                               thresholded=True)
     d2 = kops.plan_conv_launch(16, 16, 128, 128, 3, 3, stride=2,
-                               padding=1, backend="pallas", nb=8)
+                               padding=1, backend="pallas", nb=8,
+                               thresholded=True)
     assert d1["impl"] == "direct" and d2["impl"] == "im2col"
 
 
 def test_auto_int32_conv_estimate_bounds_the_compiler():
     """A direct conv of int32 sums is judged by conv_int32_vmem_bytes,
-    which bounds the v5e compiler's scoped-VMEM asks from above: every
-    stride-1 3x3 conv of ReActNet-A goes to im2col under auto (the
-    direct kernel asks 32-52 MB there), a small map stays direct, and
+    which bounds the v5e compiler's scoped-VMEM asks from above: at
+    every stride-1 3x3 shape of ReActNet-A the forced direct kernel
+    (which asks 32-52 MB there) is estimated over the budget, where
+    auto puts the conv on the MXU; a small map's estimate fits, and
     the packed form keeps its estimate."""
     for hw, c, asked_mb in ((112, 32, 41.61), (56, 128, 48.28),
                             (28, 256, 42.34), (14, 512, 31.98),
                             (7, 1024, 48.32)):
+        auto = kops.plan_conv_launch(hw, hw, c, c, 3, 3, stride=1,
+                                     padding=1, backend="pallas", nb=64)
+        assert auto["impl"] == "mxu" and "vmem_bytes" not in auto
         d = kops.plan_conv_launch(hw, hw, c, c, 3, 3, stride=1, padding=1,
-                                  backend="pallas", nb=64)
-        assert d["impl"] == "im2col"
+                                  backend="pallas", nb=64, impl="direct")
+        assert d["impl"] == "direct"
         assert d["vmem_bytes"] >= asked_mb * 2 ** 20
+        assert d["vmem_bytes"] > d["vmem_budget"]
     small = kops.plan_conv_launch(8, 8, 1024, 1024, 3, 3, stride=1,
-                                  padding=1, backend="pallas", nb=64)
+                                  padding=1, backend="pallas", nb=64,
+                                  impl="direct")
     assert small["impl"] == "direct"
     assert 5.37 * 2 ** 20 <= small["vmem_bytes"] <= small["vmem_budget"]
     packed = kops.plan_conv_launch(32, 32, 128, 128, 3, 3, stride=1,
@@ -383,18 +411,21 @@ def test_auto_int32_conv_estimate_bounds_the_compiler():
 
 
 def test_reactnet_auto_plan_is_the_im2col_plan():
-    """The builder's default plan (conv_impl="auto") resolves every conv
-    to im2col by the shared estimate: the same launches and tuning keys
-    as the pinned plan."""
+    """The builder's default plan (conv_impl="auto") puts all 26
+    residual convs on the MXU: no tuning keys, 27 launches (the stem's
+    pack and 26 epilogues); the pinned im2col plan still compiles, with
+    53."""
     auto = graph.compile(reactnet_a_imagenet(), backend="pallas", batch=64)
     pinned = graph.compile(reactnet_a_imagenet(), backend="pallas",
                            batch=64, conv_impl="im2col")
-    assert [s.args.get("impl") for s in auto.plan] == \
-        [s.args.get("impl") for s in pinned.plan]
-    assert auto.tuning_keys == pinned.tuning_keys
-    assert auto.launch_count() == pinned.launch_count() == 53
-    assert all("> budget" in s.detail or "strided window" in s.detail
-               for s in auto.plan if s.name.endswith("conv3x3"))
+    convs = [s for s in auto.plan if s.kind == "residual_conv"]
+    assert len(convs) == 26
+    assert all(s.args["impl"] == "mxu" and not s.keys for s in convs)
+    assert all("impl=mxu (int32 sums: XLA int8 conv)" in s.detail
+               for s in convs)
+    assert auto.tuning_keys == auto.tuning_keys_for_batch(8) == ()
+    assert auto.launch_count() == 27
+    assert pinned.launch_count() == 53 and len(pinned.tuning_keys) == 26
 
 
 def test_residual_traffic():
@@ -421,4 +452,5 @@ def test_compile_records_residual_spans():
         spans.disable()
     got = [s for s in spans.collect() if s[0] == "setup.residual"]
     assert [s[5]["block"] for s in got] == ["block1", "block2", "block3"]
+    assert all(s[5]["impl3"] == s[5]["impl1"] == "mxu" for s in got)
     assert all(s[2] >= s[1] for s in got)

@@ -73,7 +73,7 @@ def test_one_request_leaves_its_chain():
     t_after = time.time_ns()
     got = spans.collect()
     (compile_span,) = _named(got, "setup.compile")
-    assert compile_span[5] == {"steps": len(cb.plan)}
+    assert compile_span[5] == {"steps": len(cb.plan), "mxu_convs": 0}
     serve = _named(got, "serve.")
     assert sorted(s[0] for s in serve) == sorted(CHAIN)
     by = {s[0]: s for s in serve}
@@ -99,6 +99,25 @@ def test_one_request_leaves_its_chain():
     # on time.time_ns(): inside the calls that made them
     slack = 5_000_000
     assert all(t_before - slack <= s[1] <= s[2] <= t_after + slack for s in got)
+
+
+@pytest.mark.parametrize("name,impl,mxu", [
+    ("reactnet", "auto", 26), ("reactnet", "im2col", 0),
+    ("binarynet", "auto", 0), ("alexnet", "im2col", 0),
+])
+def test_compile_counts_mxu_convs(name, impl, mxu):
+    """``setup.compile`` counts the conv steps planned on the MXU: all
+    26 of ReActNet-A's at the benchmark's batch, none where forced off
+    it, none of the thresholded convs of BinaryNet and AlexNet."""
+    from repro.core.workloads import alexnet_imagenet, binarynet_cifar10
+    from repro.graph.ir import reactnet_a_imagenet
+
+    wl = {"reactnet": reactnet_a_imagenet, "binarynet": binarynet_cifar10,
+          "alexnet": alexnet_imagenet}[name]()
+    spans.enable()
+    cb = graph.compile(wl, backend="pallas", batch=64, conv_impl=impl)
+    (span,) = _named(spans.collect(), "setup.compile")
+    assert span[5] == {"steps": len(cb.plan), "mxu_convs": mxu}
 
 
 def test_first_marks_a_levels_first_touch_only():

@@ -110,14 +110,17 @@ def _epilogue(hw, c, groups, stride, border, pack):
         ((6, cp), F32), ((n_rows, hw, cp), I32)
 
 
-def _sums_conv(hw, c):
-    """A 3x3 stride-1 conv of int32 sums (ReActNet-A's form) for 8
-    images, through binary_conv2d's default impl="auto" dispatch."""
+def _sums_conv(hw, c, impl, k=3, stride=1, f=None):
+    """A k x k conv of int32 sums (ReActNet-A's form) for 8 images,
+    through binary_conv2d's dispatch with the given impl."""
+    f = f or c
+
     def fn(x, w):
         return ops.binary_conv2d(PackedArray(x, c, -1),
-                                 PackedArray(w, c, 2), stride=1, padding=1,
-                                 backend="pallas")
-    return fn, ((8, hw, hw, c // 32), U32), ((3, 3, c // 32, c), U32)
+                                 PackedArray(w, c, 2), stride=stride,
+                                 padding=(k - 1) // 2, backend="pallas",
+                                 impl=impl)
+    return fn, ((8, hw, hw, c // 32), U32), ((k, k, c // 32, f), U32)
 
 
 # name -> (function, operand (shape, dtype)s), at the padded shapes
@@ -147,13 +150,12 @@ KERNELS = {
     "residual_epilogue block1.2": _epilogue(112, 32, 2, 1, False, True),
     "residual_epilogue block2.1": _epilogue(56, 64, 1, 2, True, True),
     "residual_epilogue block13.2": _epilogue(7, 1024, 1, 1, False, False),
-    # ReActNet-A's stride-1 3x3 convs, int32 sums, under the default
-    # dispatch: the int32 estimate sends block3 and block13 to im2col
-    # (their direct kernels ask 48 MB of VMEM), and keeps a map it
-    # bounds below the budget direct
-    "popcount_gemm block3.conv3x3": _sums_conv(56, 128),
-    "popcount_gemm block13.conv3x3": _sums_conv(7, 1024),
-    "packed_conv2d 8x8x1024 sums": _sums_conv(8, 1024),
+    # ReActNet-A's stride-1 3x3 convs, int32 sums, forced off the MXU:
+    # block3 and block13 as im2col (their direct kernels ask 48 MB of
+    # VMEM), and direct a map the int32 estimate bounds below the budget
+    "popcount_gemm block3.conv3x3": _sums_conv(56, 128, "im2col"),
+    "popcount_gemm block13.conv3x3": _sums_conv(7, 1024, "im2col"),
+    "packed_conv2d 8x8x1024 sums": _sums_conv(8, 1024, "direct"),
     "xnor_gemm pack_out": (
         lambda x, w, a, t: xnor_gemm(x, w, a, threshold_vec=t,
                                      pack_out=True),
@@ -181,10 +183,32 @@ def test_binarynet_forward_compiles_for_v5e(one_chip):
     assert _kernels(hlo) == cb.launch_count() == 8
 
 
+# ReActNet-A's int32-sum convs as auto plans them: XLA int8 convs
+MXU_CONVS = {
+    "block1.conv3x3": (112, 32, 3, 1, 32),
+    "block2.conv3x3 stride 2": (112, 64, 3, 2, 64),
+    "block7.conv3x3": (14, 512, 3, 1, 512),
+    "block12.conv1x1": (7, 512, 1, 1, 1024),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MXU_CONVS))
+def test_mxu_conv_compiles_for_v5e(name, one_chip):
+    """The MXU form is one convolution of int8 operands with int32
+    sums (the 1x1's dot compiles to one too), and no Pallas kernel."""
+    hw, c, k, stride, f = MXU_CONVS[name]
+    fn, *shapes = _sums_conv(hw, c, "mxu", k=k, stride=stride, f=f)
+    hlo = _compile(fn, one_chip, *shapes)
+    assert _kernels(hlo) == 0
+    convs = [ln for ln in hlo.splitlines() if " convolution(" in ln]
+    assert len(convs) == 1 and "s32[" in convs[0], convs
+
+
 def test_residual_forward_compiles_for_v5e(one_chip):
     """A small residual spec's whole forward at batch 8 on the kernels
-    its plan picks (direct 3x3 convs, im2col at stride 2 and for the
-    1x1s, the epilogues): every planned launch is a Pallas kernel."""
+    its plan picks (its convs on the MXU, the stem's pack and the
+    epilogues as Pallas kernels): every planned launch is a Pallas
+    kernel."""
     spec = residual_net("tiny-reactnet", (32, 32, 3), 32,
                         ((64, 1), (128, 2), (128, 1)), 10)
     cb = graph.compile(spec, backend="pallas", batch=8)
@@ -194,4 +218,4 @@ def test_residual_forward_compiles_for_v5e(one_chip):
         params)
     x = jax.ShapeDtypeStruct((8, 32, 32, 3), F32, sharding=one_chip)
     hlo = jax.jit(cb.apply).lower(params, x).compile().as_text()
-    assert _kernels(hlo) == cb.launch_count() == 13
+    assert _kernels(hlo) == cb.launch_count() == 7
